@@ -25,6 +25,7 @@ from benchmarks import (appa_low_contention, appb_engine_validation,  # noqa: E4
                         fig_cache_replacement, fig_engine_wall,
                         fig_fault_recovery, fig_prefix_sharing,
                         fig_radix_trie, five_minute_rule, roofline_table)
+from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
 
 # (name, module, smoke-mode kwargs).  Modules without a size knob are
 # already tiny/analytical and run unchanged in smoke mode.
@@ -62,6 +63,7 @@ def main(argv=None) -> int:
     ap.add_argument("filters", nargs="*",
                     help="only run modules whose name contains a filter")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     t0 = time.time()
     failures = []

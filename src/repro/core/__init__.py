@@ -9,6 +9,7 @@ from repro.core.cost_model import (  # noqa: F401
     calibrated_cost_model,
     fit_linear_model,
     get_hardware,
+    hardware_for_device,
     profile_synthetic,
 )
 from repro.core.csp import (  # noqa: F401

@@ -61,8 +61,23 @@ HARDWARE = {
 }
 
 
+# ``jax.Device.device_kind`` -> the HARDWARE entry with that chip's peaks
+DEVICE_KINDS = {
+    "TPU v5 lite": "tpu_v5e",
+}
+
+
 def get_hardware(name: str) -> HardwareConfig:
     return HARDWARE[name]
+
+
+def hardware_for_device(device_kind: str) -> HardwareConfig:
+    """Peak figures of the chip JAX reports; an unknown kind is an error,
+    never a default."""
+    if device_kind not in DEVICE_KINDS:
+        raise ValueError(f"no peak figures for device kind {device_kind!r}; "
+                         f"known: {sorted(DEVICE_KINDS)}")
+    return HARDWARE[DEVICE_KINDS[device_kind]]
 
 
 # --------------------------------------------------------------------- #

@@ -3,7 +3,7 @@ registry.
 
 vLLM-style paging, now REAL: under ``EngineConfig.plane="paged"`` the
 serving engine stores attention KV in shared per-layer page pools
-``(num_pages, page_size, Hkv, D)`` and this allocator's block tables ARE
+``(num_pages, Hkv, page_size, D)`` and this allocator's block tables ARE
 the physical page map those pools are indexed with (the Pallas paged
 decode kernel dereferences them via scalar prefetch).  The allocator
 enforces exactly the page-rounded ``sum(m) <= M`` constraint the

@@ -16,7 +16,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.distributed.context import shard_map_compat
 
 NEG_INF = -1e30
 
@@ -70,7 +69,7 @@ def make_seqsharded_decode_attn(mesh: Mesh, *, seq_axis: str = "model"):
             return flash_decode_seqsharded(qs, ks, vs, valid,
                                            axis_name=seq_axis)
 
-        return shard_map_compat(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(dp, None, None), P(dp, seq_axis, None, None),
                       P(dp, seq_axis, None, None), P(dp)),
@@ -142,7 +141,7 @@ def make_seqsharded_decode_attn_partials(mesh: Mesh, *,
             return flash_decode_seqsharded_partials(qs, ks, vs, valid,
                                                     axis_name=seq_axis)
 
-        return shard_map_compat(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(dp, None, None), P(dp, seq_axis, None, None),
                       P(dp, seq_axis, None, None), P(dp)),

@@ -9,25 +9,9 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 from jax.sharding import Mesh
 
 _MESH: Optional[Mesh] = None
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=...)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
 
 
 def set_mesh(mesh: Optional[Mesh]) -> None:

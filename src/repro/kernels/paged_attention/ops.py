@@ -6,8 +6,9 @@
 chunk's K/V straight into the pools); on CPU they lower to jit-friendly
 jnp block-table gathers (``ref``) instead of interpret-mode Pallas —
 the interpreter re-traces per grid instance and would dominate the
-offline suite's wall time.  Both backends read the SAME pooled layout
-``(num_pages, page_size, Hkv, D)`` through the same tables."""
+offline suite's wall time.  Both backends read the SAME head-major
+pooled layout ``(num_pages, Hkv, page_size, D)`` through the same
+tables."""
 from __future__ import annotations
 
 import functools
@@ -29,7 +30,7 @@ def paged_decode(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
                  block_tables: jnp.ndarray,
                  context_lens: jnp.ndarray) -> jnp.ndarray:
     """Backend-dispatched paged decode: q (B,H,D); pools
-    (P, page, Hkv, D); block_tables (B, npages) int32; context_lens (B,)
+    (P, Hkv, page, D); block_tables (B, npages) int32; context_lens (B,)
     -> (B,H,D).  Safe to call inside an enclosing jit (the backend check
     is trace-time static)."""
     if _on_cpu():
@@ -45,12 +46,12 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                            context_lens: jnp.ndarray, *,
                            interpret: bool | None = None) -> jnp.ndarray:
-    """q (B,H,D); pools (P, page, Hkv, D); block_tables (B, npages) int32;
+    """q (B,H,D); pools (P, Hkv, page, D); block_tables (B, npages) int32;
     context_lens (B,) int32 -> (B,H,D)."""
     if interpret is None:
         interpret = _on_cpu()
     B, H, D = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[1]
     G = H // Hkv
     qg = q.reshape(B, Hkv, G, D)
     out = paged_decode_bhd(qg, k_pool, v_pool,
@@ -65,7 +66,7 @@ def paged_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                   block_tables: jnp.ndarray, starts: jnp.ndarray,
                   lengths: jnp.ndarray):
     """Backend-dispatched fused paged prefill: q (B,c,H,D); k/v
-    (B,c,Hkv,D); pools (P, page, Hkv, D); block_tables (B, maxp) int32;
+    (B,c,Hkv,D); pools (P, Hkv, page, D); block_tables (B, maxp) int32;
     starts/lengths (B,) -> (out (B,c,H,D), new_k_pool, new_v_pool).
     Writes the chunk's rows into the pools (padded rows drop) and
     attends causally over [own pages ++ chunk].  Safe inside an
@@ -86,11 +87,11 @@ def paged_prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             lengths: jnp.ndarray, *,
                             interpret: bool | None = None):
     """Pallas-kernel entry: q (B,c,H,D); k/v (B,c,Hkv,D); pools
-    (P, page, Hkv, D) -> (out (B,c,H,D), new_k_pool, new_v_pool)."""
+    (P, Hkv, page, D) -> (out (B,c,H,D), new_k_pool, new_v_pool)."""
     if interpret is None:
         interpret = _on_cpu()
     B, c, H, D = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[1]
     G = H // Hkv
     qg = (q.reshape(B, c, Hkv, G, D).transpose(0, 2, 1, 3, 4)
           .reshape(B, Hkv, c * G, D))
@@ -109,13 +110,17 @@ def decode_attention_dense(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            context_lens: jnp.ndarray, *, page_size: int = 64,
                            interpret: bool | None = None) -> jnp.ndarray:
     """Dense-cache decode through the paged kernel: the contiguous cache
-    (B, S, Hkv, D) is viewed as a page pool with identity block tables.
-    q (B,H,D) -> (B,H,D)."""
+    (B, S, Hkv, D) is re-laid out as a head-major page pool with
+    identity block tables.  q (B,H,D) -> (B,H,D)."""
     B, S, Hkv, D = k.shape
     assert S % page_size == 0, (S, page_size)
     npages = S // page_size
-    k_pool = k.reshape(B * npages, page_size, Hkv, D)
-    v_pool = v.reshape(B * npages, page_size, Hkv, D)
+
+    def as_pool(x):
+        return (x.reshape(B, npages, page_size, Hkv, D)
+                .transpose(0, 1, 3, 2, 4)
+                .reshape(B * npages, Hkv, page_size, D))
+    k_pool, v_pool = as_pool(k), as_pool(v)
     block_tables = (jnp.arange(B)[:, None] * npages +
                     jnp.arange(npages)[None, :]).astype(jnp.int32)
     return paged_decode_attention(q, k_pool, v_pool, block_tables,

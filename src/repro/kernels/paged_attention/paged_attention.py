@@ -1,7 +1,7 @@
 """Paged attention kernels (Pallas, TPU target) — flash reduction over pages.
 
-Two ops share the pooled ``(num_pages, page_size, Hkv, D)`` layout and
-the scalar-prefetched block-table addressing:
+Two ops share the head-major pooled ``(num_pages, Hkv, page_size, D)``
+layout and the scalar-prefetched block-table addressing:
 
 * ``paged_decode_bhd`` — flash-decoding, one token per row.
 * ``paged_prefill_bhd`` — the FUSED chunked-prefill step: per grid
@@ -62,8 +62,8 @@ def _pa_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
     @pl.when(in_range)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale           # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                   # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G, page)
         pos = ip * page_size + jax.lax.broadcasted_iota(
@@ -125,10 +125,10 @@ def _pp_kernel(block_tables_ref, starts_ref, lengths_ref,  # scalar prefetch
     hit = ((base + jidx == start + ridx)
            & (ridx < ln)).astype(jnp.float32)           # (pg, c)
     keep = 1.0 - jnp.sum(hit, axis=1, keepdims=True)    # (pg, 1)
-    k_old = kp_ref[0, :, 0, :].astype(jnp.float32)      # (pg, D)
-    v_old = vp_ref[0, :, 0, :].astype(jnp.float32)
-    nkp_ref[0, :, 0, :] = (keep * k_old + hit @ kc).astype(nkp_ref.dtype)
-    nvp_ref[0, :, 0, :] = (keep * v_old + hit @ vc).astype(nvp_ref.dtype)
+    k_old = kp_ref[0, 0].astype(jnp.float32)            # (pg, D)
+    v_old = vp_ref[0, 0].astype(jnp.float32)
+    nkp_ref[0, 0] = (keep * k_old + hit @ kc).astype(nkp_ref.dtype)
+    nvp_ref[0, 0] = (keep * v_old + hit @ vc).astype(nvp_ref.dtype)
 
     q2 = q_ref[0, 0].astype(jnp.float32) * scale        # (c*G, D)
 
@@ -174,7 +174,7 @@ def paged_prefill_bhd(q: jnp.ndarray, kc: jnp.ndarray, vc: jnp.ndarray,
     """Fused paged-prefill: gather-write-attend in ONE pass.
 
     q (B, Hkv, c*G, D) — the chunk's queries, head-grouped; kc/vc
-    (B, Hkv, c, D) — the chunk's new K/V rows; pools (P, page, Hkv, D).
+    (B, Hkv, c, D) — the chunk's new K/V rows; pools (P, Hkv, page, D).
     Returns (out (B, Hkv, c*G, D), new_k_pool, new_v_pool); the pools
     are updated IN PLACE (``input_output_aliases``) — only pages the
     block tables own are touched, every other page keeps its bytes.
@@ -182,13 +182,13 @@ def paged_prefill_bhd(q: jnp.ndarray, kc: jnp.ndarray, vc: jnp.ndarray,
     B, Hkv, cG, D = q.shape
     c = kc.shape[2]
     G = cG // c
-    P, page, _, _ = k_pool.shape
+    P, _, page, _ = k_pool.shape
     maxp = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
 
     def pool_idx(b, h, ip, bt, st, ln):
         np_owned = jnp.maximum((st[b] + ln[b] + page - 1) // page, 1)
-        return (bt[b, jnp.minimum(ip, np_owned - 1)], 0, h, 0)
+        return (bt[b, jnp.minimum(ip, np_owned - 1)], h, 0, 0)
 
     kernel = functools.partial(_pp_kernel, page_size=page, chunk=c,
                                groups=G, scale=scale)
@@ -199,13 +199,13 @@ def paged_prefill_bhd(q: jnp.ndarray, kc: jnp.ndarray, vc: jnp.ndarray,
             pl.BlockSpec((1, 1, cG, D), lambda b, h, ip, bt, st, ln: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, c, D), lambda b, h, ip, bt, st, ln: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, c, D), lambda b, h, ip, bt, st, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D), pool_idx),
-            pl.BlockSpec((1, page, 1, D), pool_idx),
+            pl.BlockSpec((1, 1, page, D), pool_idx),
+            pl.BlockSpec((1, 1, page, D), pool_idx),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, cG, D), lambda b, h, ip, bt, st, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D), pool_idx),
-            pl.BlockSpec((1, page, 1, D), pool_idx),
+            pl.BlockSpec((1, 1, page, D), pool_idx),
+            pl.BlockSpec((1, 1, page, D), pool_idx),
         ],
         scratch_shapes=[
             pltpu.VMEM((cG, D), jnp.float32),
@@ -231,9 +231,9 @@ def paged_prefill_bhd(q: jnp.ndarray, kc: jnp.ndarray, vc: jnp.ndarray,
 def paged_decode_bhd(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
                      block_tables: jnp.ndarray, context_lens: jnp.ndarray, *,
                      interpret: bool = False) -> jnp.ndarray:
-    """q (B, Hkv, G, D); pools (P, page, Hkv, D) -> out (B, Hkv, G, D)."""
+    """q (B, Hkv, G, D); pools (P, Hkv, page, D) -> out (B, Hkv, G, D)."""
     B, Hkv, G, D = q.shape
-    P, page, _, _ = k_pool.shape
+    P, _, page, _ = k_pool.shape
     npages = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
 
@@ -245,8 +245,8 @@ def paged_decode_bhd(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
             # q: all G heads of this kv head
             pl.BlockSpec((1, 1, G, D), lambda b, h, ip, bt, cl: (b, h, 0, 0)),
             # k/v page selected through the block table
-            pl.BlockSpec((1, page, 1, D), lambda b, h, ip, bt, cl: (bt[b, ip], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D), lambda b, h, ip, bt, cl: (bt[b, ip], 0, h, 0)),
+            pl.BlockSpec((1, 1, page, D), lambda b, h, ip, bt, cl: (bt[b, ip], h, 0, 0)),
+            pl.BlockSpec((1, 1, page, D), lambda b, h, ip, bt, cl: (bt[b, ip], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ip, bt, cl: (b, h, 0, 0)),
         scratch_shapes=[

@@ -17,16 +17,14 @@ import jax.numpy as jnp
 def paged_decode_reference(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                            context_lens: jnp.ndarray) -> jnp.ndarray:
-    """q (B,H,D); pools (P, page, Hkv, D); block_tables (B, npages);
+    """q (B,H,D); pools (P, Hkv, page, D); block_tables (B, npages);
     context_lens (B,) -> out (B,H,D)."""
     B, H, D = q.shape
-    page = k_pool.shape[1]
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[1]
     G = H // Hkv
-    npages = block_tables.shape[1]
-    S = npages * page
-    k = k_pool[block_tables].reshape(B, S, Hkv, D)  # gather pages
-    v = v_pool[block_tables].reshape(B, S, Hkv, D)
+    k = gather_pages(k_pool, block_tables)          # (B, S, Hkv, D)
+    v = gather_pages(v_pool, block_tables)
+    S = k.shape[1]
     qg = q.reshape(B, Hkv, G, D)
     s = jnp.einsum("bhgd,bkhd->bhgk", qg, k, preferred_element_type=jnp.float32)
     s = s / jnp.sqrt(jnp.asarray(D, jnp.float32))
@@ -37,15 +35,24 @@ def paged_decode_reference(q: jnp.ndarray, k_pool: jnp.ndarray,
     return out.reshape(B, H, D)
 
 
+def gather_pages(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
+    """Position-ordered view of each row's pages: a (P, Hkv, page, D)
+    pool through (B, npages) tables -> (B, npages*page, Hkv, D)."""
+    B, npages = block_tables.shape
+    _, Hkv, pg, D = pool.shape
+    return (pool[block_tables].transpose(0, 1, 3, 2, 4)
+            .reshape(B, npages * pg, Hkv, D))
+
+
 def scatter_rows(pool: jnp.ndarray, dest: jnp.ndarray,
                  rows: jnp.ndarray) -> jnp.ndarray:
-    """Write rows into a (P, page, Hkv, D) pool at flat token positions
-    ``dest`` (OOB = drop).  rows (..., Hkv, D); dest (...,) int32."""
-    P, pg, Hkv, D = pool.shape
-    flat = pool.reshape(P * pg, Hkv, D)
-    flat = flat.at[dest.reshape(-1)].set(
+    """Write rows into a (P, Hkv, page, D) pool at flat token positions
+    ``dest`` = page * page_size + slot (OOB = drop).  rows (..., Hkv, D);
+    dest (...,) int32."""
+    _, Hkv, pg, D = pool.shape
+    dest = dest.reshape(-1)
+    return pool.at[dest // pg, :, dest % pg].set(
         rows.reshape(-1, Hkv, D), mode="drop")
-    return flat.reshape(P, pg, Hkv, D)
 
 
 def paged_prefill_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -56,7 +63,7 @@ def paged_prefill_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """Gather-write-attend oracle for the fused prefill kernel.
 
     q (B, c, H, D); k/v (B, c, Hkv, D) — the chunk's projected rows;
-    pools (P, page, Hkv, D); block_tables (B, maxp); starts/lengths (B,).
+    pools (P, Hkv, page, D); block_tables (B, maxp); starts/lengths (B,).
     Returns (attn out (B, c, H, D), new_k_pool, new_v_pool).
 
     Table slot j covers absolute positions [j*page, (j+1)*page), so the
@@ -69,15 +76,15 @@ def paged_prefill_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     untouchable by construction.
     """
     B, c, H, D = q.shape
-    P, pg, Hkv, _ = k_pool.shape
+    P, Hkv, pg, _ = k_pool.shape
     maxp = block_tables.shape[1]
     Smax = maxp * pg
     G = H // Hkv
     positions = starts[:, None] + jnp.arange(c)[None, :]        # (B, c)
     valid = jnp.arange(c)[None, :] < lengths[:, None]           # (B, c)
 
-    kg = k_pool[block_tables].reshape(B, Smax, Hkv, D)
-    vg = v_pool[block_tables].reshape(B, Smax, Hkv, D)
+    kg = gather_pages(k_pool, block_tables)                     # (B, Smax, Hkv, D)
+    vg = gather_pages(v_pool, block_tables)
     rows = jnp.broadcast_to(jnp.arange(B)[:, None], (B, c))
     loc = jnp.where(valid, positions, Smax)                     # OOB drop
     kg = kg.at[rows, loc].set(k, mode="drop")

@@ -184,8 +184,6 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0 - t_lower
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax<=0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     hlo = compiled.as_text()
     colls = collective_bytes(hlo, num_devices=chips)

@@ -2,7 +2,10 @@
 
 Runs the continuous-batching engine on a workload with the selected
 scheduling / cache-replacement policy (the paper's deployment path) and
-prints the §5.1 metrics.  CPU-scale reduced configs by default.
+prints the §5.1 metrics.  Reduced configs by default, ``--full`` for
+published widths.  The cost model takes the peak figures of the device
+JAX finds (``hardware_for_device``), so this runs on a chip whose peaks
+are recorded; any other device, the CPU included, is an error.
 """
 from __future__ import annotations
 
@@ -13,8 +16,10 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
-from repro.core import TheoreticalCostModel, get_hardware, make_scheduler
+from repro.core import (TheoreticalCostModel, hardware_for_device,
+                        make_scheduler)
 from repro.data import azureconv_like, fixed_grid, hetero_mix, longform_like
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import model as M
 from repro.serving import Engine, EngineConfig
 
@@ -47,6 +52,7 @@ def main(argv=None) -> int:
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:
@@ -62,7 +68,8 @@ def main(argv=None) -> int:
     sched = make_scheduler(args.scheduler, args.M, S=args.cache_len,
                            replacement=args.replacement,
                            use_histogram=args.histogram)
-    cm = TheoreticalCostModel(cfg, get_hardware("tpu_v5e"))
+    cm = TheoreticalCostModel(
+        cfg, hardware_for_device(jax.devices()[0].device_kind))
     eng = Engine(cfg, params, sched,
                  EngineConfig(nslots=args.nslots, cache_len=args.cache_len,
                               chunk=args.chunk), cost_model=cm)

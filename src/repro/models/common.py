@@ -10,13 +10,22 @@ import jax.numpy as jnp
 Params = Dict[str, Any]
 
 
+def scaled_normal(key, shape, scale: float, dtype) -> jnp.ndarray:
+    """``N(0, 1) * scale`` drawn in float32, then cast to ``dtype``.  The
+    barrier keeps XLA from folding ``scale`` into the sampler's own
+    constants, so a draw inside ``jit`` is bit-identical to the eager
+    one."""
+    z = jax.lax.optimization_barrier(
+        jax.random.normal(key, shape, dtype=jnp.float32))
+    return (z * scale).astype(dtype)
+
+
 def dense_init(key, in_dim: int, out_dim: int, dtype) -> jnp.ndarray:
-    scale = 1.0 / math.sqrt(in_dim)
-    return (jax.random.normal(key, (in_dim, out_dim), dtype=jnp.float32) * scale).astype(dtype)
+    return scaled_normal(key, (in_dim, out_dim), 1.0 / math.sqrt(in_dim), dtype)
 
 
 def embed_init(key, vocab: int, dim: int, dtype) -> jnp.ndarray:
-    return (jax.random.normal(key, (vocab, dim), dtype=jnp.float32) * 0.02).astype(dtype)
+    return scaled_normal(key, (vocab, dim), 0.02, dtype)
 
 
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:

@@ -57,11 +57,16 @@ def _init_layer(cfg: ModelConfig, key) -> Params:
     return p
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, rng) -> Params:
+    """Random parameters from ``rng``, generated on the device in one
+    program.  Layers are drawn one at a time (``lax.map``) into their
+    stacked slots, so no float32 draw of a whole stacked weight is ever
+    live; every value equals the eager per-layer draw bit for bit."""
     dtype = jnp.dtype(cfg.dtype)
     k_embed, k_head, k_layers, k_patch = jax.random.split(rng, 4)
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    layers = jax.vmap(lambda k: _init_layer(cfg, k))(layer_keys)
+    layers = jax.lax.map(lambda k: _init_layer(cfg, k), layer_keys)
     params: Params = {
         "embed": embed_init(k_embed, cfg.padded_vocab, cfg.d_model, dtype),
         "ln_f": jnp.zeros((cfg.d_model,), dtype),

@@ -15,8 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.context import shard_map_compat
-from repro.models.common import Params, dense_init, gated_mlp, gated_mlp_init
+from repro.models.common import (Params, dense_init, gated_mlp,
+                                 gated_mlp_init, scaled_normal)
 
 
 def init_moe(key, cfg: ModelConfig) -> Params:
@@ -25,12 +25,12 @@ def init_moe(key, cfg: ModelConfig) -> Params:
     ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], cfg.d_model, E, dtype),
-        "wi_gate": (jax.random.normal(ks[1], (E, cfg.d_model, cfg.moe_d_ff), jnp.float32)
-                    * (cfg.d_model ** -0.5)).astype(dtype),
-        "wi_up": (jax.random.normal(ks[2], (E, cfg.d_model, cfg.moe_d_ff), jnp.float32)
-                  * (cfg.d_model ** -0.5)).astype(dtype),
-        "wo": (jax.random.normal(ks[3], (E, cfg.moe_d_ff, cfg.d_model), jnp.float32)
-               * (cfg.moe_d_ff ** -0.5)).astype(dtype),
+        "wi_gate": scaled_normal(ks[1], (E, cfg.d_model, cfg.moe_d_ff),
+                                 cfg.d_model ** -0.5, dtype),
+        "wi_up": scaled_normal(ks[2], (E, cfg.d_model, cfg.moe_d_ff),
+                               cfg.d_model ** -0.5, dtype),
+        "wo": scaled_normal(ks[3], (E, cfg.moe_d_ff, cfg.d_model),
+                            cfg.moe_d_ff ** -0.5, dtype),
     }
     if cfg.num_shared_experts:
         p["shared"] = gated_mlp_init(
@@ -165,7 +165,7 @@ def apply_moe_ep(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     # same replicated tokens — n x duplicate traffic (measured 16x on
     # cell B).  Decode steps (S=1) fall back to replicated dispatch.
     seq_spec = ep_axis if S % n == 0 else None
-    y = shard_map_compat(
+    y = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, seq_spec, None), P(None, None),
                   P(ep_axis, None, None), P(ep_axis, None, None),

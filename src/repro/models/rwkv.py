@@ -15,16 +15,34 @@ Finch low-rank parameterization (simplified: single LoRA per projection).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.common import Params, dense_init, rms_norm
 
 LORA_R = 32
+
+
+@functools.lru_cache(maxsize=None)
+def _decay_ramp(d: int) -> np.ndarray:
+    """The base log-decay ramp, linear in [-6, -1].  Compiled and run on
+    its own, outside any enclosing trace: inlined into a jitted init,
+    XLA would round the ramp differently from the eager one."""
+    ramp = jax.jit(jnp.linspace, static_argnums=2).lower(-6.0, -1.0, d)
+    return np.asarray(ramp.compile()(-6.0, -1.0), np.float32)
+
+
+def _token_shift_mix(key, n: int, d: int) -> jnp.ndarray:
+    """Token-shift mixing weights, uniform in [0.25, 0.75).  The barrier
+    keeps a draw inside ``jit`` bit-identical to the eager one."""
+    u = jax.lax.optimization_barrier(jax.random.uniform(key, (n, d)))
+    return (u * 0.5 + 0.25).astype(jnp.float32)
 
 
 def init_rwkv_layer(key, cfg: ModelConfig) -> Params:
@@ -33,7 +51,7 @@ def init_rwkv_layer(key, cfg: ModelConfig) -> Params:
     ks = jax.random.split(key, 12)
     p = {
         # time-mix
-        "mu": (jax.random.uniform(ks[0], (4, d)) * 0.5 + 0.25).astype(jnp.float32),
+        "mu": _token_shift_mix(ks[0], 4, d),
         "wr": dense_init(ks[1], d, d, dtype),
         "wk": dense_init(ks[2], d, d, dtype),
         "wv": dense_init(ks[3], d, d, dtype),
@@ -41,11 +59,11 @@ def init_rwkv_layer(key, cfg: ModelConfig) -> Params:
         "wo": dense_init(ks[5], d, d, dtype),
         "decay_lora_a": dense_init(ks[6], d, LORA_R, dtype),
         "decay_lora_b": dense_init(ks[7], LORA_R, d, dtype),
-        "decay_base": (jnp.linspace(-6.0, -1.0, d)).astype(jnp.float32),
+        "decay_base": jnp.asarray(_decay_ramp(d)),
         "bonus": (jnp.zeros((d,))).astype(jnp.float32),
         "ln_x": jnp.zeros((d,), dtype),  # per-head groupnorm scale
         # channel-mix
-        "cm_mu": (jax.random.uniform(ks[8], (2, d)) * 0.5 + 0.25).astype(jnp.float32),
+        "cm_mu": _token_shift_mix(ks[8], 2, d),
         "cm_r": dense_init(ks[9], d, d, dtype),
         "cm_k": dense_init(ks[10], d, cfg.d_ff, dtype),
         "cm_v": dense_init(ks[11], cfg.d_ff, d, dtype),

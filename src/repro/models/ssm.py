@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import Params, dense_init
+from repro.models.common import Params, dense_init, scaled_normal
 
 CONV_K = 4
 
@@ -28,7 +28,7 @@ def init_ssm(key, cfg: ModelConfig) -> Params:
     ks = jax.random.split(key, 7)
     return {
         "in_proj": dense_init(ks[0], d, 2 * di, dtype),        # x, z gate
-        "conv_w": (jax.random.normal(ks[1], (CONV_K, di), jnp.float32) * 0.2).astype(dtype),
+        "conv_w": scaled_normal(ks[1], (CONV_K, di), 0.2, dtype),
         "dt_proj": dense_init(ks[2], di, di, dtype),
         "dt_bias": jnp.zeros((di,), dtype),
         "bc_proj": dense_init(ks[3], di, 2 * N, dtype),        # B_t, C_t

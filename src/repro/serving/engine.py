@@ -24,8 +24,8 @@ Execution plane — THREE data planes, selected by ``EngineConfig.plane``:
   compile-count regression test).  Inactive rows carry length 0 and are
   provably inert.
 * ``"paged"`` — the allocator's block tables become the PHYSICAL memory
-  layout (PR 4): attention KV lives in shared per-layer page pools
-  ``(num_pages, page_size, Hkv, D)`` (``serving.paged_plane``), prefill
+  layout (PR 4): attention KV lives in shared per-layer head-major page
+  pools ``(num_pages, Hkv, page_size, D)`` (``serving.paged_plane``), prefill
   writes K/V through the block table into owned pages, and decode runs
   the ``kernels.paged_attention`` flash-decoding Pallas kernel over
   scalar-prefetched block tables (jnp gather fallback on CPU).  Pooled
@@ -410,8 +410,8 @@ def _make_decode(cfg: ModelConfig, impl: str, moe_impl: str,
     return decode_many
 
 
-def _make_paged_step_fns(cfg: ModelConfig, impl: str, moe_impl: str):
-    pf, df = build_paged_fns(cfg, impl=impl, moe_impl=moe_impl)
+def _make_paged_step_fns(cfg: ModelConfig, moe_impl: str):
+    pf, df = build_paged_fns(cfg, moe_impl=moe_impl)
 
     def prefill_packed(params, k_pools, v_pools, grid, block_tables):
         # one coalesced host->device transfer per round: the tokens,
@@ -449,8 +449,8 @@ def _shared_decode_jit(cfg: ModelConfig, impl: str, moe_impl: str,
 
 
 @functools.lru_cache(maxsize=64)
-def _shared_paged_jits(cfg: ModelConfig, impl: str, moe_impl: str):
-    pf, df = _make_paged_step_fns(cfg, impl, moe_impl)
+def _shared_paged_jits(cfg: ModelConfig, moe_impl: str):
+    pf, df = _make_paged_step_fns(cfg, moe_impl)
     return jax.jit(pf), jax.jit(df)
 
 
@@ -515,8 +515,8 @@ class Engine:
         if self._pooled:
             pg = ecfg.page_size
             self.max_pages = -(-ecfg.cache_len // pg)
-            pool_shape = (cfg.num_layers, self.allocator.num_pages, pg,
-                          cfg.num_kv_heads, cfg.head_dim_)
+            pool_shape = (cfg.num_layers, self.allocator.num_pages,
+                          cfg.num_kv_heads, pg, cfg.head_dim_)
             self.k_pools = jnp.zeros(pool_shape, jnp.dtype(cfg.dtype))
             self.v_pools = jnp.zeros_like(self.k_pools)
             self.cache = None
@@ -651,9 +651,9 @@ class Engine:
         self._jit_fns = [prefill_jit, decode_jit, *slot]
         if self._pooled:
             if ecfg.share_jits:
-                ppf, pdf = _shared_paged_jits(*key)
+                ppf, pdf = _shared_paged_jits(cfg, ecfg.moe_impl)
             else:
-                pf, df = _make_paged_step_fns(*key)
+                pf, df = _make_paged_step_fns(cfg, ecfg.moe_impl)
                 ppf, pdf = jax.jit(pf), jax.jit(df)
             self._paged_prefill, self._paged_decode = ppf, pdf
             self._jit_fns = [ppf, pdf]   # the pooled plane uses nothing else

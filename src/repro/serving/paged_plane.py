@@ -1,7 +1,8 @@
 """Pooled-KV model steps for the engine's ``plane="paged"`` (PR 4).
 
-Attention KV lives in shared per-layer page pools ``(num_pages,
-page_size, Hkv, D)`` instead of per-slot contiguous buffers; a request's
+Attention KV lives in shared per-layer head-major page pools
+``(num_pages, Hkv, page_size, D)`` instead of per-slot contiguous
+buffers; a request's
 pages are named by the ``PagedAllocator`` block table, threaded in as a
 ``(B, max_pages)`` int32 array.  Both steps are shape-stable (fixed pool
 / table / grid shapes; prefill tokens padded to the engine's bucket
@@ -68,7 +69,7 @@ def _attn_paged_chunk(lp: Any, cfg: ModelConfig, h: jnp.ndarray,
                       block_tables: jnp.ndarray
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Chunked prefill attention against pooled KV.  h (B, c, d); pools
-    (P, page, Hkv, D); starts/lengths (B,); block_tables (B, maxp).
+    (P, Hkv, page, D); starts/lengths (B,); block_tables (B, maxp).
     Returns (attn out (B, c, q_dim-projected), new pools)."""
     B, c, _ = h.shape
     positions = starts[:, None] + jnp.arange(c)[None, :]        # (B, c)
@@ -87,7 +88,7 @@ def _attn_paged_decode(lp: Any, cfg: ModelConfig, h: jnp.ndarray,
     """One-token decode against pooled KV.  h (B, 1, d); ctx (B,) valid
     context (also the new token's position); active (B,) row mask."""
     B = h.shape[0]
-    P, pg = k_pool.shape[0], k_pool.shape[1]
+    P, pg = k_pool.shape[0], k_pool.shape[2]
     maxp = block_tables.shape[1]
     positions = ctx[:, None]
     q, k, v = attn._project_qkv(lp, cfg, h, positions)
@@ -102,8 +103,7 @@ def _attn_paged_decode(lp: Any, cfg: ModelConfig, h: jnp.ndarray,
     return out[:, None, :], new_k, new_v
 
 
-def build_paged_fns(cfg: ModelConfig, *, impl: str = "reference",
-                    moe_impl: str = "dense"
+def build_paged_fns(cfg: ModelConfig, *, moe_impl: str = "dense"
                     ) -> Tuple[Callable, Callable]:
     """Returns jit-ready ``(prefill_fn, decode_fn)`` over pooled KV.
 
@@ -114,10 +114,11 @@ def build_paged_fns(cfg: ModelConfig, *, impl: str = "reference",
               block_tables (B, maxp), active (B,))
         -> (greedy ids (B,), new_k_pools, new_v_pools)
 
-    Pools are stacked over layers: (L, P, page, Hkv, D).  Sampling is
-    fused (argmax over the real vocabulary on device); ``impl`` selects
-    only the decode backend via ``ops.paged_decode``'s dispatch — the
-    prefill backend is chosen by ``ops.paged_prefill`` itself.
+    Pools are stacked over layers: (L, P, Hkv, page, D).  Sampling is
+    fused (argmax over the real vocabulary on device).  The attention
+    backend of both steps is picked by the JAX backend alone
+    (``ops.paged_prefill`` / ``ops.paged_decode``): the Pallas kernels
+    on an accelerator, the jnp reference on the CPU.
     """
     if not paged_supported(cfg):
         raise ValueError("paged pools need unbounded dense attention, "

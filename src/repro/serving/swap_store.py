@@ -160,7 +160,7 @@ class SwapEntry:
 class PageRunEntry:
     """Page-granular snapshot: a contiguous run of a request's KV pages
     (the §8 partial-preemption unit).  ``kv`` holds the gathered pool
-    pages per layer — ``{"k": (L, n_pages, page, Hkv, D), "v": ...}`` —
+    pages per layer — ``{"k": (L, n_pages, Hkv, page, D), "v": ...}`` —
     and ``start`` is the absolute token position of the run's first
     token (always page-aligned).  Runs for one rid tile [0, suspended
     tokens) contiguously; only the topmost run may end mid-page."""
@@ -183,7 +183,7 @@ class PrefixPageEntry:
     hash, owned by no request).  ``tokens`` are the page's token ids
     (collision verification at promotion, exactly like the device
     registry); ``n_kvs`` the chain depth the replacement policy scores
-    with; ``kv`` the per-layer page snapshot ``{"k": (L, 1, page, Hkv,
+    with; ``kv`` the per-layer page snapshot ``{"k": (L, 1, Hkv, page,
     D), "v": ...}`` — or None for metadata-only shadows (the simulator
     charges virtual time without moving bytes; pass ``nbytes``)."""
     key: int

@@ -96,8 +96,8 @@ def test_flash_attention_block_shapes(bq, bk):
 def test_paged_decode_sweep(B, H, Hkv, D, page, npages, dtype):
     P = B * npages + 3                      # spare pages in the pool
     q = jax.random.normal(K(0), (B, H, D), dtype)
-    kp = jax.random.normal(K(1), (P, page, Hkv, D), dtype)
-    vp = jax.random.normal(K(2), (P, page, Hkv, D), dtype)
+    kp = jax.random.normal(K(1), (P, Hkv, page, D), dtype)
+    vp = jax.random.normal(K(2), (P, Hkv, page, D), dtype)
     bt = jax.random.permutation(K(3), P)[:B * npages].reshape(B, npages)
     bt = bt.astype(jnp.int32)
     ctx = jax.random.randint(K(4), (B,), 1, page * npages + 1)
@@ -114,8 +114,8 @@ def test_paged_decode_short_context_skips_pages():
     B, H, Hkv, D, page, npages = 1, 4, 2, 64, 16, 4
     P = B * npages
     q = jax.random.normal(K(0), (B, H, D))
-    kp = jax.random.normal(K(1), (P, page, Hkv, D))
-    vp = jax.random.normal(K(2), (P, page, Hkv, D))
+    kp = jax.random.normal(K(1), (P, Hkv, page, D))
+    vp = jax.random.normal(K(2), (P, Hkv, page, D))
     bt = jnp.arange(P).reshape(B, npages).astype(jnp.int32)
     ctx = jnp.array([1], jnp.int32)
     out = paged_decode_attention(q, kp, vp, bt, ctx)

@@ -28,8 +28,8 @@ def _mk(B, c, H, Hkv, D, page, maxp, spare=3, seed=0):
     q = jax.random.normal(K(seed), (B, c, H, D))
     k = jax.random.normal(K(seed + 1), (B, c, Hkv, D))
     v = jax.random.normal(K(seed + 2), (B, c, Hkv, D))
-    kp = jax.random.normal(K(seed + 3), (P, page, Hkv, D))
-    vp = jax.random.normal(K(seed + 4), (P, page, Hkv, D))
+    kp = jax.random.normal(K(seed + 3), (P, Hkv, page, D))
+    vp = jax.random.normal(K(seed + 4), (P, Hkv, page, D))
     bt = jax.random.permutation(K(seed + 5), P)[:B * maxp] \
         .reshape(B, maxp).astype(jnp.int32)
     return q, k, v, kp, vp, bt
@@ -89,8 +89,8 @@ def test_prefill_kernel_masked_padded_rows():
     # the partial row beyond its length wrote nothing either: positions
     # [4+3, 8) of its first page keep the original pool bytes
     p0 = int(np.asarray(bt[1])[0])
-    np.testing.assert_array_equal(np.asarray(got[1])[p0, 7:],
-                                  np.asarray(kp)[p0, 7:])
+    np.testing.assert_array_equal(np.asarray(got[1])[p0, :, 7:],
+                                  np.asarray(kp)[p0, :, 7:])
 
 
 @pytest.mark.parametrize("c", [8, 16, 64])
@@ -139,8 +139,8 @@ def test_prefill_kernel_cow_boundary_page():
     got, want = _both(q, k, v, kp, vp, bt, starts, lengths)
     _assert_triple(got, want, lengths, c)
     p0 = int(np.asarray(bt)[0, 0])
-    np.testing.assert_array_equal(np.asarray(got[1])[p0, :5],
-                                  np.asarray(kp)[p0, :5])
+    np.testing.assert_array_equal(np.asarray(got[1])[p0, :, :5],
+                                  np.asarray(kp)[p0, :, :5])
 
 
 # --------------------------------------------------------------------- #

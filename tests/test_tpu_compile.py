@@ -1,0 +1,92 @@
+"""Both paged Pallas kernels compile for a described TPU v5e.
+
+The TPU compiler is installed beside the CPU backend, so a kernel can be
+compiled ahead of time for a chip that is described and not attached.
+That catches what interpret mode cannot: block shapes the Mosaic tiling
+refuses and kernels that overflow VMEM.  Shapes are those the engine's
+paged plane serves with ``chip_smoke.py``: page 16, prefill chunk 256,
+8 rows over a 2048-token cache, bfloat16 pools.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.paged_attention.paged_attention import (paged_decode_bhd,
+                                                           paged_prefill_bhd)
+
+PAGE, CHUNK, ROWS, CACHE_LEN, POOL_PAGES = 16, 256, 8, 2048, 512
+WIDTHS = {                       # name: (Hkv, G, D)
+    "qwen3-4b": (8, 4, 128),
+    "tinyllama-1.1b": (4, 8, 64),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs go to /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip cannot be read back without one:
+    keep it out of the persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def spec(one_chip):
+    def make(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def _pool_and_tables(spec, Hkv, D):
+    pool = spec((POOL_PAGES, Hkv, PAGE, D))
+    return pool, spec((ROWS, CACHE_LEN // PAGE), jnp.int32)
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_paged_decode_compiles_for_v5e(spec, no_compile_cache, width):
+    Hkv, G, D = WIDTHS[width]
+    pool, tables = _pool_and_tables(spec, Hkv, D)
+    fn = jax.jit(functools.partial(paged_decode_bhd, interpret=False))
+    compiled = fn.lower(spec((ROWS, Hkv, G, D)), pool, pool, tables,
+                        spec((ROWS,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_paged_prefill_compiles_for_v5e(spec, no_compile_cache, width):
+    Hkv, G, D = WIDTHS[width]
+    pool, tables = _pool_and_tables(spec, Hkv, D)
+    rows = spec((ROWS,), jnp.int32)
+    chunk_kv = spec((ROWS, Hkv, CHUNK, D))
+    fn = jax.jit(functools.partial(paged_prefill_bhd, interpret=False))
+    compiled = fn.lower(spec((ROWS, Hkv, CHUNK * G, D)), chunk_kv, chunk_kv,
+                        pool, pool, tables, rows, rows).compile()
+    assert "tpu_custom_call" in compiled.as_text()
