@@ -18,17 +18,17 @@ the paged engine with sharing ON and OFF.  Reported per point:
   * wall tok/s (sharing skips the shared tokens' prefill FLOPs; both
     engines run ``share_jits=True`` + ``warmup()`` so compiles stay out
     of the timed window — PR 8),
-  * the engine's phase timers (attach / prefill / upload seconds) — the
-    wall-clock attribution of where zero-copy attach and the coalesced
-    block-table/grid uploads pay off,
+  * the engine's step-program counters (``EngineResult.phase_stats``):
+    the real prefill tokens against the tokens of the padded grids, and
+    the active decode rows per decode call,
   * prefix hits / shared tokens from the allocator stats.
 
 Asserted: outputs are token-identical with sharing on and off at every
 point (reuse is a memory/compute optimization, never a semantic one),
 and at a 75% duplicate fraction sharing holds measurably fewer resident
-pages than unshared paging AND is strictly faster wall-clock (the
-attach path replaces the shared tokens' prefill work with a registry
-pointer bump).
+pages than unshared paging, computes fewer prefill tokens, AND is
+strictly faster wall-clock (the attach path replaces the shared tokens'
+prefill work with a registry pointer bump).
 """
 from __future__ import annotations
 
@@ -59,7 +59,7 @@ def _run(cfg, params, cm, reqs, *, sharing):
                 prefix_hits=eng.allocator.stats["prefix_hits"],
                 shared_tokens=eng.allocator.stats["prefix_shared_tokens"],
                 preemptions=res.metrics.num_preemptions,
-                **{k: round(v, 6) for k, v in res.phase_stats.items()})
+                **res.phase_stats)
 
 
 def run(smoke: bool = False) -> dict:
@@ -93,9 +93,9 @@ def run(smoke: bool = False) -> dict:
         rows.append([f"{frac:.2f}",
                      off["peak_pages"], on["peak_pages"],
                      f"{off['tps']:.1f}", f"{on['tps']:.1f}",
-                     f"{on['attach_s'] * 1e3:.1f}",
-                     f"{on['prefill_s'] * 1e3:.1f}",
-                     f"{on['upload_s'] * 1e3:.1f}",
+                     off["prefill_real_tokens"], on["prefill_real_tokens"],
+                     on["prefill_grid_tokens"],
+                     f"{on['decode_rows'] / max(1, on['decode_calls']):.2f}",
                      on["prefix_hits"], on["shared_tokens"]])
         payload[f"frac_{frac}"] = {
             "unshared": {k: v for k, v in off.items() if k != "outputs"},
@@ -105,8 +105,9 @@ def run(smoke: bool = False) -> dict:
         f"fig_prefix_sharing — resident pages & tok/s vs duplicate-prefix "
         f"fraction (paged plane, {n} requests, page_size=8)",
         ["dup frac", "pages (off)", "pages (on)", "tok/s (off)",
-         "tok/s (on)", "attach ms", "prefill ms", "upload ms",
-         "hits", "shared toks"], rows)
+         "tok/s (on)", "prefill toks (off)", "prefill toks (on)",
+         "grid toks (on)", "rows/decode (on)", "hits", "shared toks"],
+        rows)
 
     # the point of the exercise: ≥8 requests sharing a 75% prefix hold
     # measurably fewer resident pages than unshared paging — and with
@@ -115,6 +116,8 @@ def run(smoke: bool = False) -> dict:
     hi = payload[f"frac_{fracs[-1]}"]
     assert hi["shared"]["peak_pages"] < hi["unshared"]["peak_pages"], hi
     assert hi["shared"]["prefix_hits"] >= n - 1, hi
+    assert hi["shared"]["prefill_real_tokens"] \
+        < hi["unshared"]["prefill_real_tokens"], hi
     assert hi["shared"]["wall_s"] < hi["unshared"]["wall_s"], hi
     # no duplicate prefix -> no CROSS-request sharing.  The radix trie
     # (PR 9) can still legitimately re-attach a recompute-preempted

@@ -272,12 +272,9 @@ def phase_serve(cfg, dev, hw, seed: int, clock: CompileClock) -> None:
     preempt = (res.metrics.num_preemptions
                + res.metrics.num_partial_preempts)
     hits = eng.swap_stats[SK.TRIE_HITS]
-    steady = wall - compile_s
     log("serve", f"finished {sum(r.finished for r in reqs)}/{len(reqs)} "
                  f"requests, {generated} tokens generated")
-    log("serve", f"compile_s={compile_s!r} run_wall_s={wall!r} "
-                 f"steady_wall_s={steady!r} "
-                 f"smoke_tokens_per_s={generated / steady!r}")
+    log("serve", f"compile_s={compile_s!r} run_wall_s={wall!r}")
     log("serve", f"preemptions={preempt} trie_hits={hits} "
                  f"batches={len(res.metrics.batches)} "
                  f"num_compiles={res.num_compiles}")

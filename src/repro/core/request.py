@@ -64,6 +64,11 @@ class Request:
     token_times: List[float] = field(default_factory=list)
     # --- SRF+Hist bookkeeping ---
     predicted_output: Optional[float] = None
+    # --- host-clock stamps (``time.perf_counter``) set by the engine:
+    # at ``Engine.submit`` and the first time the request is in a batch;
+    # their difference is the request's queue wait ---
+    wall_submitted: Optional[float] = None
+    wall_admitted: Optional[float] = None
 
     # ------------------------------------------------------------------ #
     @property

@@ -70,12 +70,16 @@ STRAGGLER_REQUEUES = "straggler_requeues"  # wall-triggered, engine-only
 WALL_ABORTED_S = "wall_aborted_s"      # engine wall measurement
 
 # --------------------------------------------------------------------- #
-# wall-clock phase attribution of the pooled step (engine phase_stats;
-# pure measurement, no simulator analogue)
+# step-program work (engine phase_stats; counts of programs dispatched,
+# no simulator analogue): prefill rounds, the tokens of their padded
+# (nslots, bucket) grids and the real ones among them, decode calls and
+# their active rows
 # --------------------------------------------------------------------- #
-ATTACH_S = "attach_s"
-PREFILL_S = "prefill_s"
-UPLOAD_S = "upload_s"
+PREFILL_ROUNDS = "prefill_rounds"
+PREFILL_GRID_TOKENS = "prefill_grid_tokens"
+PREFILL_REAL_TOKENS = "prefill_real_tokens"
+DECODE_CALLS = "decode_calls"
+DECODE_ROWS = "decode_rows"
 
 # --------------------------------------------------------------------- #
 # PagedAllocator.stats — the control plane is the SAME class on both

@@ -181,7 +181,7 @@ import functools
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field, replace
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -208,6 +208,17 @@ from repro.serving.swap_store import (KVSwapStore, SwapEntry,
                                       SwapStoreFullError, flip_bit,
                                       seal_entry, verify_entry)
 from repro.serving.txn import StepTxn, begin_step_txn
+
+# host spans of the serving step: ``repro.engine.<part>`` annotations on
+# the JAX profiler's clock, the clock of the device ops in its trace.
+# With no profiler session open an annotation costs under a
+# microsecond, so they stay on; none sits inside jitted code.
+_span = jax.profiler.TraceAnnotation
+
+
+def _spanned(name: str) -> Callable:
+    """Method decorator: the whole call is one ``name`` span."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
 
 # hard ceiling on fault-recovery retries of one step: content-keyed
 # draws are idempotent, so only attempt-keyed allocation faults can
@@ -411,9 +422,12 @@ def _make_decode(cfg: ModelConfig, impl: str, moe_impl: str,
 
 
 def _make_paged_step_fns(cfg: ModelConfig, moe_impl: str):
-    pf, df = build_paged_fns(cfg, moe_impl=moe_impl)
+    """The pooled plane's two step programs.  Their function names are
+    their XLA module names (``jit_paged_prefill_step``,
+    ``jit_paged_decode_step``), which name them in a profiler trace."""
+    pf, paged_decode_step = build_paged_fns(cfg, moe_impl=moe_impl)
 
-    def prefill_packed(params, k_pools, v_pools, grid, block_tables):
+    def paged_prefill_step(params, k_pools, v_pools, grid, block_tables):
         # one coalesced host->device transfer per round: the tokens,
         # lengths and starts of every slot ride a single (nslots,
         # bucket+2) int32 grid — [toks | lens | starts] — unpacked here
@@ -423,7 +437,7 @@ def _make_paged_step_fns(cfg: ModelConfig, moe_impl: str):
         starts = grid[:, -1]
         return pf(params, k_pools, v_pools, toks, starts, lens,
                   block_tables)
-    return prefill_packed, df
+    return paged_prefill_step, paged_decode_step
 
 
 @functools.lru_cache(maxsize=1)
@@ -563,12 +577,16 @@ class Engine:
         self._straggler: Optional[StragglerMonitor] = (
             StragglerMonitor(deadline_factor=ecfg.straggler_factor)
             if ecfg.straggler_factor else None)
-        # wall-clock phase attribution of the pooled step (zero-copy
-        # prefix attach / prefill compute / host->device uploads) —
-        # OUTSIDE the step txn like ``wall``: time spent by an aborted
-        # attempt was still spent
-        self.phase_stats: Dict[str, float] = {
-            SK.ATTACH_S: 0.0, SK.PREFILL_S: 0.0, SK.UPLOAD_S: 0.0}
+        # the engine's counters of step-program work (prefill rounds and
+        # the tokens of their padded grids against the real ones, decode
+        # calls and their active rows) — OUTSIDE the step txn like
+        # ``wall``: a program an aborted attempt dispatched still ran.
+        # Every abort point precedes the first dispatch of an attempt,
+        # so a retried step counts its programs once.
+        self.phase_stats: Dict[str, int] = {
+            SK.PREFILL_ROUNDS: 0, SK.PREFILL_GRID_TOKENS: 0,
+            SK.PREFILL_REAL_TOKENS: 0, SK.DECODE_CALLS: 0,
+            SK.DECODE_ROWS: 0}
         # in-flight async swap-out snapshots (rid -> (store entry whose
         # cache leaves are still device arrays mid-D2H, enqueue step)).
         # An entry enqueued during step N overlaps its D2H copy with
@@ -730,6 +748,7 @@ class Engine:
                 f"request {r.rid} peak KV {r.peak_kv} > cache_len")
         self.token_ids[r.rid] = list(r.prompt)
         self.outputs[r.rid] = []
+        r.wall_submitted = time.perf_counter()
         self.sched.add_request(r)
 
     # ------------------------------------------------------------------ #
@@ -914,6 +933,7 @@ class Engine:
             self._drain_swaps(rid=next(iter(self._pending_swaps)))
         return True
 
+    @_spanned("repro.engine.drain")
     def _drain_swaps(self, rid: Optional[int] = None,
                      before_step: Optional[int] = None) -> None:
         """Finalize in-flight swap-out transfers: block on the async D2H
@@ -1374,6 +1394,7 @@ class Engine:
                 self.swap_stats[SK.PARTIAL_HIT_TOKENS] += attached
         return attached
 
+    @_spanned("repro.engine.register")
     def _register_prefix(self, r: Request, m_new: int) -> None:
         """Publish the now-complete full PROMPT pages to the registry
         (generated-token pages are never shared)."""
@@ -1397,6 +1418,7 @@ class Engine:
             self.k_pools = self.k_pools.at[:, new].set(self.k_pools[:, old])
             self.v_pools = self.v_pools.at[:, new].set(self.v_pools[:, old])
 
+    @_spanned("repro.engine.upload")
     def _block_tables_device(self) -> jnp.ndarray:
         """Device-side (nslots, max_pages) block tables, cached against
         the allocator's mutation version — decode steps that allocated
@@ -1408,7 +1430,6 @@ class Engine:
         v = self.allocator.version
         if self._bt_cache is not None and self._bt_cache[0] == v:
             return self._bt_cache[1]
-        t0 = time.perf_counter()
         if self._bt_host is None:
             self._bt_host = np.zeros((self.ecfg.nslots, self.max_pages),
                                      np.int32)
@@ -1430,8 +1451,6 @@ class Engine:
         # the mirror would corrupt device tables still referenced by
         # step-txn snapshots
         self._bt_cache = (v, jnp.asarray(np.array(self._bt_host)))
-        # repro: allow-txn-coverage(phase_stats is measured wall-clock attribution - real time spent is real even on an aborted attempt; parity never compares it)
-        self.phase_stats[SK.UPLOAD_S] += time.perf_counter() - t0
         return self._bt_cache[1]
 
     def _swap_time(self, n_kvs: int) -> float:
@@ -1453,14 +1472,26 @@ class Engine:
             logits = None
             while remaining > 0:
                 step_c = min(self.ecfg.chunk, remaining)
-                toks = jnp.asarray([ids[start:start + step_c]], jnp.int32)  # repro: allow-dynamic-shape(legacy plane pre-dates bucketing; distinct lengths are bounded by the chunk ladder and pinned by the compile-count test)
-                logits, self.cache = self._prefill_one(
-                    self.params, self.cache, jnp.int32(slot), toks)
+                with _span("repro.engine.upload"):
+                    toks = jnp.asarray([ids[start:start + step_c]], jnp.int32)  # repro: allow-dynamic-shape(legacy plane pre-dates bucketing; distinct lengths are bounded by the chunk ladder and pinned by the compile-count test)
+                with _span("repro.engine.prefill_call"):
+                    logits, self.cache = self._prefill_one(
+                        self.params, self.cache, jnp.int32(slot), toks)
+                self._count_prefill(step_c, step_c)
                 start += step_c
                 remaining -= step_c
             if r.m + c == r.target_context:   # this batch emits a token
-                final_tok[r.rid] = self._sample(logits)
+                with _span("repro.engine.fetch"):
+                    final_tok[r.rid] = self._sample(logits)
         return final_tok
+
+    def _count_prefill(self, grid_tokens: int, real_tokens: int) -> None:
+        """Count one prefill program call: the tokens of its grid, padding
+        included, and the real ones among them."""
+        # repro: allow-txn-coverage(phase_stats counts programs dispatched - an aborted attempt's programs still ran, and every abort point precedes an attempt's first dispatch)
+        self.phase_stats[SK.PREFILL_ROUNDS] += 1
+        self.phase_stats[SK.PREFILL_GRID_TOKENS] += grid_tokens
+        self.phase_stats[SK.PREFILL_REAL_TOKENS] += real_tokens
 
     def _run_prefill_rounds(self, plans, emits, step_fn) -> Dict[int, int]:
         """Shared bucketed round loop of the batched AND paged planes:
@@ -1477,25 +1508,29 @@ class Engine:
             if not steps:
                 break
             bucket = self._bucket_for(max(steps.values()))
-            toks = np.zeros((nslots, bucket), np.int32)
-            lens = np.zeros((nslots,), np.int32)
-            starts = np.zeros((nslots,), np.int32)
             finishing: List[Tuple[Request, int]] = []
-            for p in plans:
-                r, slot, cursor, rem = p
-                if rem <= 0:
-                    continue
-                sc = steps[slot]
-                toks[slot, :sc] = self.token_ids[r.rid][cursor:cursor + sc]
-                lens[slot] = sc
-                starts[slot] = cursor
-                p[2] += sc
-                p[3] -= sc
-                if p[3] == 0:
-                    finishing.append((r, slot))
+            with _span("repro.engine.upload"):
+                toks = np.zeros((nslots, bucket), np.int32)
+                lens = np.zeros((nslots,), np.int32)
+                starts = np.zeros((nslots,), np.int32)
+                for p in plans:
+                    r, slot, cursor, rem = p
+                    if rem <= 0:
+                        continue
+                    sc = steps[slot]
+                    toks[slot, :sc] = \
+                        self.token_ids[r.rid][cursor:cursor + sc]
+                    lens[slot] = sc
+                    starts[slot] = cursor
+                    p[2] += sc
+                    p[3] -= sc
+                    if p[3] == 0:
+                        finishing.append((r, slot))
             tok_ids = step_fn(toks, lens, starts)
+            self._count_prefill(nslots * bucket, int(lens.sum()))
             if any(emits[r.rid] for r, _ in finishing):
-                host = np.asarray(tok_ids)  # repro: allow-host-sync(per-step sampled-token fetch - ids must reach the host to extend prompts and detect EOS; (nslots,) int32 only)
+                with _span("repro.engine.fetch"):
+                    host = np.asarray(tok_ids)  # repro: allow-host-sync(per-step sampled-token fetch - ids must reach the host to extend prompts and detect EOS; (nslots,) int32 only)
                 for r, slot in finishing:
                     if emits[r.rid]:
                         final_tok[r.rid] = int(host[slot])
@@ -1508,9 +1543,11 @@ class Engine:
         emits = {r.rid: r.m + c == r.target_context for r, c in prefill_items}
 
         def step(toks, lens, starts):
-            tok_ids, self.cache = self._prefill_many(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(lens))
+            with _span("repro.engine.upload"):
+                toks_dev, lens_dev = jnp.asarray(toks), jnp.asarray(lens)
+            with _span("repro.engine.prefill_call"):
+                tok_ids, self.cache = self._prefill_many(
+                    self.params, self.cache, toks_dev, lens_dev)
             return tok_ids
 
         return self._run_prefill_rounds(plans, emits, step)
@@ -1532,13 +1569,13 @@ class Engine:
             # one coalesced upload per round — [toks | lens | starts]
             # ride a single (nslots, bucket+2) grid, unpacked on device
             # inside the jitted step (see _make_paged_step_fns)
-            t0 = time.perf_counter()
-            grid = jnp.asarray(np.concatenate(
-                [toks, lens[:, None], starts[:, None]], axis=1))
-            self.phase_stats[SK.UPLOAD_S] += time.perf_counter() - t0
-            tok_ids, self.k_pools, self.v_pools = self._paged_prefill(
-                self.params, self.k_pools, self.v_pools, grid,
-                block_tables)
+            with _span("repro.engine.upload"):
+                grid = jnp.asarray(np.concatenate(
+                    [toks, lens[:, None], starts[:, None]], axis=1))
+            with _span("repro.engine.prefill_call"):
+                tok_ids, self.k_pools, self.v_pools = self._paged_prefill(
+                    self.params, self.k_pools, self.v_pools, grid,
+                    block_tables)
             return tok_ids
 
         return self._run_prefill_rounds(plans, emits, step)
@@ -1564,34 +1601,43 @@ class Engine:
             (r.rid, self.slot_of[r.rid], r.m, len(self.token_ids[r.rid]))
             for r, _ in decode_items))
         st = self._decode_state
-        t0 = time.perf_counter()
         if st is not None and st["key"] == key:
             toks_dev, ctx_dev = st["toks"], st["ctx"]
             active_dev, ones = st["active"], st["ones"]
         else:
-            toks = np.zeros((nslots,), np.int32)
-            ctx = np.zeros((nslots,), np.int32)
-            active = np.zeros((nslots,), bool)
-            for r, _ in decode_items:
-                slot = self.slot_of[r.rid]
-                toks[slot] = self.token_ids[r.rid][-1]
-                ctx[slot] = r.m
-                active[slot] = True
-            packed = jnp.asarray(np.stack([toks, ctx]))  # ONE i32 upload
-            toks_dev, ctx_dev = packed[0], packed[1]
-            active_dev = jnp.asarray(active)
-            ones = active_dev.astype(jnp.int32)
-        self.phase_stats[SK.UPLOAD_S] += time.perf_counter() - t0
-        tok_ids, self.k_pools, self.v_pools = self._paged_decode(
-            self.params, self.k_pools, self.v_pools, toks_dev,
-            ctx_dev, self._block_tables_device(), active_dev)
+            with _span("repro.engine.upload"):
+                toks = np.zeros((nslots,), np.int32)
+                ctx = np.zeros((nslots,), np.int32)
+                active = np.zeros((nslots,), bool)
+                for r, _ in decode_items:
+                    slot = self.slot_of[r.rid]
+                    toks[slot] = self.token_ids[r.rid][-1]
+                    ctx[slot] = r.m
+                    active[slot] = True
+                packed = jnp.asarray(np.stack([toks, ctx]))  # ONE upload
+                toks_dev, ctx_dev = packed[0], packed[1]
+                active_dev = jnp.asarray(active)
+                ones = active_dev.astype(jnp.int32)
+        block_tables = self._block_tables_device()
+        with _span("repro.engine.decode_call"):
+            tok_ids, self.k_pools, self.v_pools = self._paged_decode(
+                self.params, self.k_pools, self.v_pools, toks_dev,
+                ctx_dev, block_tables, active_dev)
+        self._count_decode(len(decode_items))
         nxt = tuple(sorted(
             (r.rid, self.slot_of[r.rid], r.m + 1,
              len(self.token_ids[r.rid]) + 1) for r, _ in decode_items))
         self._decode_state = {"key": nxt, "toks": tok_ids,
                               "ctx": ctx_dev + ones,
                               "active": active_dev, "ones": ones}
-        return np.asarray(tok_ids)  # repro: allow-host-sync(per-step sampled-token fetch - ids must reach the host to extend prompts and detect EOS; (nslots,) int32 only)
+        with _span("repro.engine.fetch"):
+            return np.asarray(tok_ids)  # repro: allow-host-sync(per-step sampled-token fetch - ids must reach the host to extend prompts and detect EOS; (nslots,) int32 only)
+
+    def _count_decode(self, rows: int) -> None:
+        """Count one decode program call and its active rows."""
+        # repro: allow-txn-coverage(phase_stats counts programs dispatched - an aborted attempt's programs still ran, and every abort point precedes an attempt's first dispatch)
+        self.phase_stats[SK.DECODE_CALLS] += 1
+        self.phase_stats[SK.DECODE_ROWS] += rows
 
     # ------------------------------------------------------------------ #
     def step(self) -> int:
@@ -1612,6 +1658,12 @@ class Engine:
         fault."""
         if not self.sched.has_work():
             return 0
+        with _span("repro.engine.step"):
+            return self._step_retrying()
+
+    def _step_retrying(self) -> int:
+        """``step``'s attempts: roll back and retry on a survivable
+        fault, re-raise on an accounting bug."""
         self._step_no += 1  # repro: allow-txn-coverage(step identity deliberately survives rollback - a retried attempt is the SAME step, and drain/fault keying depends on that)
         for attempt in range(_MAX_STEP_ATTEMPTS):
             # repro: allow-txn-coverage(attempt bookkeeping is reset at every attempt start and keys the per-attempt fault draws - restoring it would replay attempt 0's faults forever)
@@ -1648,6 +1700,7 @@ class Engine:
             f"step {self._step_no}: {_MAX_STEP_ATTEMPTS} fault-recovery "
             f"attempts exhausted")
 
+    @_spanned("repro.engine.txn")
     def _begin_txn(self) -> StepTxn:
         """Open the step transaction: common participants via
         ``begin_step_txn`` plus the engine-local view.  Device KV needs
@@ -1737,39 +1790,43 @@ class Engine:
         """One attempt at the current step (see ``step``)."""
         t0 = time.perf_counter()
         self.allocator.now = self.now   # replacement-policy clock
-        batch = self.sched.get_next_batch()
+        with _span("repro.engine.schedule"):
+            batch = self.sched.get_next_batch()
         swap_s = 0.0
         num_swap_out = num_swap_in = 0
-        # page-level partial preemptions first: chronologically they
-        # precede any later FULL preemption of the same victim, and the
-        # tail pages must be snapshotted before the remainder is
-        for r, npages, n_tokens, mode in batch.partial_preempted:
-            if not r.running:
-                # the victim was ALSO fully preempted later this round.
-                # A swap-mode shed folds into the full suspend: the
-                # full-preempt path below snapshots the WHOLE table
-                # (tail included) as one run, so skip the data movement
-                # but keep the per-run virtual-time charge — the
-                # simulator charges it at shed time too.  A
-                # recompute-mode shed must still come OFF the table so
-                # the full snapshot (or release) matches the request's
-                # reduced bookkeeping (suspended_m excludes it).
-                if mode == "swap":
+        with _span("repro.engine.preempt"):
+            # page-level partial preemptions first: chronologically
+            # they precede any later FULL preemption of the same victim,
+            # and the tail pages must be snapshotted before the
+            # remainder is
+            for r, npages, n_tokens, mode in batch.partial_preempted:
+                if not r.running:
+                    # the victim was ALSO fully preempted later this
+                    # round.  A swap-mode shed folds into the full
+                    # suspend: the full-preempt path below snapshots the
+                    # WHOLE table (tail included) as one run, so skip the
+                    # data movement but keep the per-run virtual-time
+                    # charge — the simulator charges it at shed time
+                    # too.  A recompute-mode shed must still come OFF the
+                    # table so the full snapshot (or release) matches the
+                    # request's reduced bookkeeping (suspended_m
+                    # excludes it).
+                    if mode == "swap":
+                        swap_s += self._swap_time(n_tokens)
+                        num_swap_out += 1
+                    else:
+                        removed = self.allocator.free_tail(r.rid, npages)
+                        if self.ecfg.check_invariants:
+                            assert removed == n_tokens, (r.rid, removed,
+                                                         n_tokens)
+                    continue
+                if self._shed_tail(r, npages, n_tokens, mode):
                     swap_s += self._swap_time(n_tokens)
                     num_swap_out += 1
-                else:
-                    removed = self.allocator.free_tail(r.rid, npages)
-                    if self.ecfg.check_invariants:
-                        assert removed == n_tokens, (r.rid, removed,
-                                                     n_tokens)
-                continue
-            if self._shed_tail(r, npages, n_tokens, mode):
-                swap_s += self._swap_time(n_tokens)
-                num_swap_out += 1
-        for victim in batch.preempted:
-            s, o = self._handle_preempted(victim)
-            swap_s += s
-            num_swap_out += o
+            for victim in batch.preempted:
+                s, o = self._handle_preempted(victim)
+                swap_s += s
+                num_swap_out += o
         if not batch.items:
             # swap-outs still happened: owe their virtual-time charge to
             # the next executed batch (mirrors the simulator's carry)
@@ -1785,51 +1842,56 @@ class Engine:
         # swap-ins: restore suspended re-admissions before classification
         # so they re-enter as decodes/short prefills, not full refills;
         # partially shed requests restore their tail runs the same way
-        for r, _ in batch.items:
-            if r.suspended:
-                swap_s += self._swap_time(r.suspended_m)
-                num_swap_in += 1
-                (self._swap_in_paged if self._pooled else self._swap_in)(r)
-            elif r.tail_suspended_m > 0:
-                swap_s += self._swap_time(r.tail_suspended_m)
-                num_swap_in += 1
-                self._swap_in_tail(r)
+        with _span("repro.engine.swap_in"):
+            for r, _ in batch.items:
+                if r.suspended:
+                    swap_s += self._swap_time(r.suspended_m)
+                    num_swap_in += 1
+                    (self._swap_in_paged if self._pooled
+                     else self._swap_in)(r)
+                elif r.tail_suspended_m > 0:
+                    swap_s += self._swap_time(r.tail_suspended_m)
+                    num_swap_in += 1
+                    self._swap_in_tail(r)
 
-        # classify + virtual-time the batch up front
-        spec = BatchSpec()
-        prefill_items: List[Tuple[Request, int]] = []
-        decode_items: List[Tuple[Request, int]] = []
-        for r, c in batch.items:
-            if r.generated > 0 and c == 1 and r.remaining_prefill == 1:
-                decode_items.append((r, c))
-                spec.decodes.append((c, r.m))
-            else:
-                prefill_items.append((r, c))
-                spec.prefills.append((c, r.m))
+        with _span("repro.engine.admit"):
+            # classify + virtual-time the batch up front
+            spec = BatchSpec()
+            prefill_items: List[Tuple[Request, int]] = []
+            decode_items: List[Tuple[Request, int]] = []
+            admitted_at = time.perf_counter()
+            for r, c in batch.items:
+                if r.wall_admitted is None:      # first time in a batch
+                    r.wall_admitted = admitted_at
+                if r.generated > 0 and c == 1 and r.remaining_prefill == 1:
+                    decode_items.append((r, c))
+                    spec.decodes.append((c, r.m))
+                else:
+                    prefill_items.append((r, c))
+                    spec.prefills.append((c, r.m))
 
-        # claim slots + control-plane allocation BEFORE pricing the
-        # batch: the prefix attach may PROMOTE host-demoted pages, and
-        # any allocation (or CoW remap) may reclaim-and-DEMOTE registry
-        # entries — those host-link swap_time charges belong to THIS
-        # batch's virtual time, mirroring the simulator shadow
-        t_attach = time.perf_counter()
-        for r, c in prefill_items:
-            if r.rid not in self.slot_of:
-                self._claim_slot(r.rid, reset=not self._pooled)
-            skip = 0
-            if (self._pooled and self.ecfg.prefix_sharing
-                    and r.m == 0 and not self.allocator.has(r.rid)):
-                skip = self._attach_prefix(r, c)
-            if self._pooled:
-                self._prefix_skip[r.rid] = skip
-            self.allocator.allocate(r.rid, c - skip)
-            if self._pooled:
-                self._cow_guard(r.rid, r.m + skip)
-        self.phase_stats[SK.ATTACH_S] += time.perf_counter() - t_attach
-        for r, _ in decode_items:
-            self.allocator.allocate(r.rid, 1)
-            if self._pooled:
-                self._cow_guard(r.rid, r.m)
+            # claim slots + control-plane allocation BEFORE pricing the
+            # batch: the prefix attach may PROMOTE host-demoted pages,
+            # and any allocation (or CoW remap) may reclaim-and-DEMOTE
+            # registry entries — those host-link swap_time charges
+            # belong to THIS batch's virtual time, mirroring the
+            # simulator shadow
+            for r, c in prefill_items:
+                if r.rid not in self.slot_of:
+                    self._claim_slot(r.rid, reset=not self._pooled)
+                skip = 0
+                if (self._pooled and self.ecfg.prefix_sharing
+                        and r.m == 0 and not self.allocator.has(r.rid)):
+                    skip = self._attach_prefix(r, c)
+                if self._pooled:
+                    self._prefix_skip[r.rid] = skip
+                self.allocator.allocate(r.rid, c - skip)
+                if self._pooled:
+                    self._cow_guard(r.rid, r.m + skip)
+            for r, _ in decode_items:
+                self.allocator.allocate(r.rid, 1)
+                if self._pooled:
+                    self._cow_guard(r.rid, r.m)
         swap_s += self._tier_swap_s
         self._tier_swap_s = 0.0
 
@@ -1844,9 +1906,7 @@ class Engine:
                       "paged": (self._run_prefills_paged if self._pooled
                                 else self._run_prefills_batched)}[
                                     self.ecfg.plane]
-            t_pf = time.perf_counter()
             final_tok = runner(prefill_items)
-            self.phase_stats[SK.PREFILL_S] += time.perf_counter() - t_pf
             for r, c in prefill_items:
                 m_new = r.m + c
                 generated = r.advance(c, self.now)
@@ -1867,16 +1927,20 @@ class Engine:
             if self._pooled:
                 host = self._run_decodes_paged(decode_items)
             else:
-                toks = np.zeros((nslots,), np.int32)
-                mask = np.zeros((nslots,), bool)
-                for r, _ in decode_items:
-                    slot = self.slot_of[r.rid]
-                    toks[slot] = self.token_ids[r.rid][-1]
-                    mask[slot] = True
-                tok_ids, self.cache = self._decode_many(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(mask))
-                host = np.asarray(tok_ids)  # repro: allow-host-sync(per-step sampled-token fetch - ids must reach the host to extend prompts and detect EOS; (nslots,) int32 only)
+                with _span("repro.engine.upload"):
+                    toks = np.zeros((nslots,), np.int32)
+                    mask = np.zeros((nslots,), bool)
+                    for r, _ in decode_items:
+                        slot = self.slot_of[r.rid]
+                        toks[slot] = self.token_ids[r.rid][-1]
+                        mask[slot] = True
+                    toks_dev, mask_dev = jnp.asarray(toks), jnp.asarray(mask)
+                with _span("repro.engine.decode_call"):
+                    tok_ids, self.cache = self._decode_many(
+                        self.params, self.cache, toks_dev, mask_dev)
+                self._count_decode(len(decode_items))
+                with _span("repro.engine.fetch"):
+                    host = np.asarray(tok_ids)  # repro: allow-host-sync(per-step sampled-token fetch - ids must reach the host to extend prompts and detect EOS; (nslots,) int32 only)
             for r, c in decode_items:
                 slot = self.slot_of[r.rid]
                 r.advance(c, self.now)
@@ -1978,9 +2042,10 @@ class EngineResult:
     swap_stats: Dict[str, float] = field(default_factory=dict)
     num_compiles: int = 0
     recovery_stats: Dict[str, float] = field(default_factory=dict)
-    # wall-clock attribution of the pooled step (attach_s / prefill_s /
-    # upload_s) — the fig_prefix_sharing phase columns
-    phase_stats: Dict[str, float] = field(default_factory=dict)
+    # the engine's step-program counters (prefill rounds, grid and real
+    # prefill tokens, decode calls and rows) — fig_prefix_sharing's
+    # columns
+    phase_stats: Dict[str, int] = field(default_factory=dict)
 
 
 # --------------------------------------------------------------------- #

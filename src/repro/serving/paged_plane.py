@@ -105,13 +105,14 @@ def _attn_paged_decode(lp: Any, cfg: ModelConfig, h: jnp.ndarray,
 
 def build_paged_fns(cfg: ModelConfig, *, moe_impl: str = "dense"
                     ) -> Tuple[Callable, Callable]:
-    """Returns jit-ready ``(prefill_fn, decode_fn)`` over pooled KV.
+    """Returns jit-ready ``(prefill_fn, paged_decode_step)`` over pooled
+    KV.
 
     prefill_fn(params, k_pools, v_pools, tokens (B, bucket),
                starts (B,), lengths (B,), block_tables (B, maxp))
         -> (greedy ids (B,), new_k_pools, new_v_pools)
-    decode_fn(params, k_pools, v_pools, tokens (B,), ctx (B,),
-              block_tables (B, maxp), active (B,))
+    paged_decode_step(params, k_pools, v_pools, tokens (B,), ctx (B,),
+                      block_tables (B, maxp), active (B,))
         -> (greedy ids (B,), new_k_pools, new_v_pools)
 
     Pools are stacked over layers: (L, P, Hkv, page, D).  Sampling is
@@ -152,8 +153,8 @@ def build_paged_fns(cfg: ModelConfig, *, moe_impl: str = "dense"
         toks = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
         return toks, new_k, new_v
 
-    def decode_fn(params, k_pools, v_pools, tokens, ctx, block_tables,
-                  active):
+    def paged_decode_step(params, k_pools, v_pools, tokens, ctx,
+                          block_tables, active):
         if tokens.ndim == 1:
             tokens = tokens[:, None]
         x, _ = M._embed(cfg, params, tokens, ctx[:, None], None)
@@ -172,4 +173,4 @@ def build_paged_fns(cfg: ModelConfig, *, moe_impl: str = "dense"
         toks = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
         return toks, new_k, new_v
 
-    return prefill_fn, decode_fn
+    return prefill_fn, paged_decode_step

@@ -37,11 +37,16 @@ from repro.core.kvcache import BlockTable, PagedAllocator
 from repro.core.request import Request
 
 # Request fields mutated by the state machine mid-step.  ``token_times``
-# is the one mutable container; everything else is a scalar.
+# is the one mutable container; everything else is a scalar.  The host
+# clock stamps ride along: a rolled-back attempt's admission is undone,
+# and the retry stamps it again.  ``wall_submitted`` is written only at
+# submit, before any step, but txn-coverage counts every request store
+# in the engine module, so it is listed too.
 _REQUEST_FIELDS = (
     "m", "generated", "running", "preemptions", "suspended",
     "suspended_m", "swaps", "tail_suspended_m", "partial_preemptions",
     "swap_out_m", "first_token_time", "finish_time", "predicted_output",
+    "wall_submitted", "wall_admitted",
 )
 
 
