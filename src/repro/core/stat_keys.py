@@ -72,14 +72,15 @@ WALL_ABORTED_S = "wall_aborted_s"      # engine wall measurement
 # --------------------------------------------------------------------- #
 # step-program work (engine phase_stats; counts of programs dispatched,
 # no simulator analogue): prefill rounds, the tokens of their padded
-# (nslots, bucket) grids and the real ones among them, decode calls and
-# their active rows
+# (nslots, bucket) grids and the real ones among them, decode calls,
+# their active rows and the KV pages those rows attend over
 # --------------------------------------------------------------------- #
 PREFILL_ROUNDS = "prefill_rounds"
 PREFILL_GRID_TOKENS = "prefill_grid_tokens"
 PREFILL_REAL_TOKENS = "prefill_real_tokens"
 DECODE_CALLS = "decode_calls"
 DECODE_ROWS = "decode_rows"
+DECODE_LIVE_PAGES = "decode_live_pages"
 
 # --------------------------------------------------------------------- #
 # PagedAllocator.stats — the control plane is the SAME class on both

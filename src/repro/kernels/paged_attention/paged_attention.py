@@ -15,19 +15,30 @@ layout and the scalar-prefetched block-table addressing:
   gather is ever materialized — HBM traffic is the owned pages once,
   plus one page-sized write per page the chunk lands on.
 
-One grid instance per (batch, kv-head, page): the page index comes from the
-*scalar-prefetched* block table (``PrefetchScalarGridSpec``), i.e. the
-BlockSpec index_map dereferences ``block_tables[b, ip]`` — the TPU DMA
-engine streams exactly the pages owned by the request, never the whole
-pool.  The fp32 (acc, m, l) scratch persists across the page axis
-(innermost, sequential on TPU); pages beyond ``context_len`` are skipped
-with ``pl.when`` so short requests cost O(their length), which is exactly
-the ``m``-linear decode cost the paper's cost model assumes.
+Both find pages through the *scalar-prefetched* block table
+(``PrefetchScalarGridSpec``): a BlockSpec index_map, or the kernel's
+own copy, dereferences ``block_tables[b, ...]``, so the TPU DMA engine
+streams the pages a row owns, never the whole pool.  The fp32 (acc, m, l) scratch persists
+across the innermost grid axis, which runs in order on the chip.
+
+Decode runs one grid instance per (row, block of ``ppb`` table
+entries), for all kv heads at once: ``ppb`` comes from the page size
+(``pages_per_block``: about 256 positions, at most 16 pages).  An
+instance attends q ``(Hkv, G, D)`` against the block's
+``(Hkv, ppb * page, D)`` in one batched matmul over the kv heads.  Where
+the pool's tiles allow it (D a multiple of 128, pages of a multiple of 8
+rows) the pools stay in HBM and the kernel copies each live page's
+contiguous ``(Hkv, page, D)`` slab itself, block j+1's while block j
+computes; a page at or past ``context_len`` is never copied.  Elsewhere
+each page of a block is a pipelined operand of its own, re-aimed past
+the context at a page it already holds, so nothing is copied for it.
+A block wholly past the context skips its compute but still costs a
+grid step, which is why a block holds many pages.  Prefill still runs
+one grid instance per (row, kv head, page).
 
 This is the TPU-native adaptation of vLLM's CUDA PagedAttention: instead
-of a warp-per-token gather, pages are DMA'd as (page_size, D) VMEM tiles
-and the G=H/Hkv query heads of a kv head are batched into a single
-(G, page_size) MXU matmul.
+of a warp-per-token gather, pages are DMA'd as VMEM tiles and the
+G=H/Hkv query heads of a kv head are batched into one MXU matmul.
 """
 from __future__ import annotations
 
@@ -40,49 +51,113 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+BLOCK_TOKENS = 256      # decode: context positions per grid instance,
+MAX_BLOCK_PAGES = 16    # in at most this many pages
 
 
-def _pa_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
-               q_ref, k_ref, v_ref, o_ref,
-               acc_ref, m_ref, l_ref, *,
-               page_size: int, scale: float):
+def pages_per_block(page_size: int, npages: int) -> int:
+    """Block-table entries one decode grid instance covers: about
+    ``BLOCK_TOKENS`` positions, at most ``MAX_BLOCK_PAGES``, at least one
+    page, at most the table."""
+    return max(1, min(npages, MAX_BLOCK_PAGES, BLOCK_TOKENS // page_size))
+
+
+def _pd_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
+               q_ref, *refs, page_size: int, ppb: int, npages: int,
+               scale: float, dma: bool):
+    if dma:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref = refs
+    else:
+        k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+        o_ref, acc_ref, m_ref, l_ref = refs[2 * ppb:]
     b = pl.program_id(0)
-    ip = pl.program_id(2)
-    npages = pl.num_programs(2)
+    j = pl.program_id(1)
+    nblk = pl.num_programs(1)
+    block = ppb * page_size
+    ctx = jnp.minimum(context_lens_ref[b], npages * page_size)
+    live_pages = (ctx + page_size - 1) // page_size
 
-    @pl.when(ip == 0)
+    def live_in(blk):
+        """How many of block ``blk``'s pages hold context."""
+        return jnp.clip(live_pages - blk * ppb, 0, ppb)
+
+    def page_copies(blk, i):
+        """K and V copies of block ``blk``'s i-th page, one contiguous
+        (Hkv, page, D) slab each, into that block's buffer slot."""
+        page = block_tables_ref[b, blk * ppb + i]
+        slot = blk % 2
+        rows = pl.ds(i * page_size, page_size)
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, :, rows],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, :, rows],
+                                      sems.at[1, slot]))
+
+    def fetch(blk):
+        n = live_in(blk)
+        for i in range(ppb):
+            @pl.when(i < n)
+            def _start():
+                for c in page_copies(blk, i):
+                    c.start()
+
+    @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        if dma:
+            fetch(0)
 
-    ctx = context_lens_ref[b]
-    in_range = ip * page_size < ctx
+    if dma:
+        # double buffer: block j+1's pages stream in while block j computes
+        @pl.when(j + 1 < nblk)
+        def _prefetch():
+            fetch(j + 1)
 
-    @pl.when(in_range)
+    def block_kv():
+        """The block's K and V, (Hkv, block, D) in float32."""
+        if not dma:
+            return [jnp.concatenate([r[0].astype(jnp.float32) for r in rs],
+                                    axis=1) for rs in (k_refs, v_refs)]
+        slot = j % 2
+        n = live_in(j)
+        for i in range(ppb):
+            @pl.when(i < n)
+            def _wait():
+                for c in page_copies(j, i):
+                    c.wait()
+
+            # a page past the context is never copied: its V rows get
+            # weight 0, and must not hold stale NaNs
+            @pl.when(i >= n)
+            def _clear():
+                rows = pl.ds(i * page_size, page_size)
+                v_buf[slot, :, rows] = jnp.zeros_like(v_buf[slot, :, rows])
+        return k_buf[slot].astype(jnp.float32), v_buf[slot].astype(jnp.float32)
+
+    @pl.when(j * block < ctx)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale           # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)                   # (page, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, page)
-        pos = ip * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+        q = q_ref[0].astype(jnp.float32) * scale              # (Hkv, G, D)
+        k, v = block_kv()                                      # (Hkv, block, D)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)  # (Hkv, G, block)
+        pos = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(pos < ctx, s, NEG_INF)
 
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
         acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(ip == npages - 1)
+    @pl.when(j == nblk - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _pp_kernel(block_tables_ref, starts_ref, lengths_ref,  # scalar prefetch
@@ -231,33 +306,63 @@ def paged_prefill_bhd(q: jnp.ndarray, kc: jnp.ndarray, vc: jnp.ndarray,
 def paged_decode_bhd(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
                      block_tables: jnp.ndarray, context_lens: jnp.ndarray, *,
                      interpret: bool = False) -> jnp.ndarray:
-    """q (B, Hkv, G, D); pools (P, Hkv, page, D) -> out (B, Hkv, G, D)."""
+    """q (B, Hkv, G, D); pools (P, Hkv, page, D) -> out (B, Hkv, G, D).
+    A row of context 0 gets zeros."""
     B, Hkv, G, D = q.shape
     P, _, page, _ = k_pool.shape
     npages = block_tables.shape[1]
+    ppb = pages_per_block(page, npages)
     scale = 1.0 / math.sqrt(D)
+    # Mosaic slices a ref only along whole tiles, so the kernel's own
+    # copies need D a multiple of 128 lanes and pages of a multiple of 8
+    # rows; other shapes pipeline each page as an operand of its own
+    dma = D % 128 == 0 and page % 8 == 0
 
-    kernel = functools.partial(_pa_kernel, page_size=page, scale=scale)
+    def page_spec(i):
+        """The i-th page of block j, all kv heads.  An entry at or past
+        the context re-aims at the row's last live page of the same
+        operand, so the pipeline, seeing its block index unchanged,
+        copies nothing; a row of context 0 stays on its first entry."""
+        def index(b, j, bt, cl):
+            live = jnp.maximum(
+                (jnp.minimum(cl[b], npages * page) + page - 1) // page, 1)
+            e = jnp.minimum(jnp.minimum(j, (live - 1) // ppb) * ppb + i,
+                            live - 1)
+            return (bt[b, e], 0, 0, 0)
+        return pl.BlockSpec((1, Hkv, page, D), index)
+
+    if dma:
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        pool_specs, pools = [hbm, hbm], (k_pool, v_pool)
+        buf = pltpu.VMEM((2, Hkv, ppb * page, D), k_pool.dtype)
+        copy_scratch = [buf, buf, pltpu.SemaphoreType.DMA((2, 2))]
+    else:
+        # each pool goes in once per page of a block, one pipelined
+        # operand each; a custom call's operands are references, so no
+        # pool is copied
+        pages = [page_spec(i) for i in range(ppb)]
+        pool_specs, pools = pages + pages, (k_pool,) * ppb + (v_pool,) * ppb
+        copy_scratch = []
+    row = pl.BlockSpec((1, Hkv, G, D), lambda b, j, bt, cl: (b, 0, 0, 0))
+    kernel = functools.partial(_pd_kernel, page_size=page, ppb=ppb,
+                               npages=npages, scale=scale, dma=dma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, npages),
-        in_specs=[
-            # q: all G heads of this kv head
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ip, bt, cl: (b, h, 0, 0)),
-            # k/v page selected through the block table
-            pl.BlockSpec((1, 1, page, D), lambda b, h, ip, bt, cl: (bt[b, ip], h, 0, 0)),
-            pl.BlockSpec((1, 1, page, D), lambda b, h, ip, bt, cl: (bt[b, ip], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ip, bt, cl: (b, h, 0, 0)),
+        grid=(B, pl.cdiv(npages, ppb)),
+        in_specs=[row, *pool_specs],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
+            *copy_scratch,
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, context_lens, q, k_pool, v_pool)
+    )(block_tables, context_lens, q, *pools)

@@ -579,14 +579,15 @@ class Engine:
             if ecfg.straggler_factor else None)
         # the engine's counters of step-program work (prefill rounds and
         # the tokens of their padded grids against the real ones, decode
-        # calls and their active rows) — OUTSIDE the step txn like
+        # calls, their active rows and the pages those rows attend
+        # over) — OUTSIDE the step txn like
         # ``wall``: a program an aborted attempt dispatched still ran.
         # Every abort point precedes the first dispatch of an attempt,
         # so a retried step counts its programs once.
         self.phase_stats: Dict[str, int] = {
             SK.PREFILL_ROUNDS: 0, SK.PREFILL_GRID_TOKENS: 0,
             SK.PREFILL_REAL_TOKENS: 0, SK.DECODE_CALLS: 0,
-            SK.DECODE_ROWS: 0}
+            SK.DECODE_ROWS: 0, SK.DECODE_LIVE_PAGES: 0}
         # in-flight async swap-out snapshots (rid -> (store entry whose
         # cache leaves are still device arrays mid-D2H, enqueue step)).
         # An entry enqueued during step N overlaps its D2H copy with
@@ -1623,7 +1624,7 @@ class Engine:
             tok_ids, self.k_pools, self.v_pools = self._paged_decode(
                 self.params, self.k_pools, self.v_pools, toks_dev,
                 ctx_dev, block_tables, active_dev)
-        self._count_decode(len(decode_items))
+        self._count_decode(decode_items)
         nxt = tuple(sorted(
             (r.rid, self.slot_of[r.rid], r.m + 1,
              len(self.token_ids[r.rid]) + 1) for r, _ in decode_items))
@@ -1633,11 +1634,15 @@ class Engine:
         with _span("repro.engine.fetch"):
             return np.asarray(tok_ids)  # repro: allow-host-sync(per-step sampled-token fetch - ids must reach the host to extend prompts and detect EOS; (nslots,) int32 only)
 
-    def _count_decode(self, rows: int) -> None:
-        """Count one decode program call and its active rows."""
+    def _count_decode(self, decode_items) -> None:
+        """Count one decode program call, its active rows, and the pages
+        they attend over: a row at ``m`` reads ``m + 1`` positions."""
+        pg = self.ecfg.page_size
         # repro: allow-txn-coverage(phase_stats counts programs dispatched - an aborted attempt's programs still ran, and every abort point precedes an attempt's first dispatch)
         self.phase_stats[SK.DECODE_CALLS] += 1
-        self.phase_stats[SK.DECODE_ROWS] += rows
+        self.phase_stats[SK.DECODE_ROWS] += len(decode_items)
+        self.phase_stats[SK.DECODE_LIVE_PAGES] += sum(
+            -(-(r.m + 1) // pg) for r, _ in decode_items)
 
     # ------------------------------------------------------------------ #
     def step(self) -> int:
@@ -1938,7 +1943,7 @@ class Engine:
                 with _span("repro.engine.decode_call"):
                     tok_ids, self.cache = self._decode_many(
                         self.params, self.cache, toks_dev, mask_dev)
-                self._count_decode(len(decode_items))
+                self._count_decode(decode_items)
                 with _span("repro.engine.fetch"):
                     host = np.asarray(tok_ids)  # repro: allow-host-sync(per-step sampled-token fetch - ids must reach the host to extend prompts and detect EOS; (nslots,) int32 only)
             for r, c in decode_items:

@@ -97,8 +97,10 @@ def _attn_paged_decode(lp: Any, cfg: ModelConfig, h: jnp.ndarray,
     dest = jnp.where(active, page_idx * pg + ctx % pg, P * pg)
     new_k = _scatter_rows(k_pool, dest, k[:, 0])
     new_v = _scatter_rows(v_pool, dest, v[:, 0])
-    # write-then-attend: context_lens = ctx + 1 includes the new token
-    out = pa_ops.paged_decode(q[:, 0], new_k, new_v, block_tables, ctx + 1)
+    # write-then-attend: context_lens = ctx + 1 includes the new token;
+    # an idle slot attends over nothing (its output is never read)
+    out = pa_ops.paged_decode(q[:, 0], new_k, new_v, block_tables,
+                              jnp.where(active, ctx + 1, 0))
     out = out.reshape(B, cfg.q_dim) @ lp["wo"]
     return out[:, None, :], new_k, new_v
 

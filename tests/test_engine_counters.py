@@ -23,7 +23,8 @@ from repro.serving import Engine, EngineConfig
 from repro.serving.faults import FaultSpec
 
 COUNTERS = (SK.PREFILL_ROUNDS, SK.PREFILL_GRID_TOKENS,
-            SK.PREFILL_REAL_TOKENS, SK.DECODE_CALLS, SK.DECODE_ROWS)
+            SK.PREFILL_REAL_TOKENS, SK.DECODE_CALLS, SK.DECODE_ROWS,
+            SK.DECODE_LIVE_PAGES)
 
 
 @pytest.fixture(scope="module")
@@ -68,11 +69,14 @@ def churn(vocab, n=8, seed=4):
 
 class CallLog:
     """Wraps the pooled plane's two programs and records what each call
-    was given: the grid's shape and real lengths, the active rows."""
+    was given: the grid's shape and real lengths, the active rows and
+    the pages they attend over (a row at ``ctx`` reads ``ctx + 1``
+    positions)."""
 
     def __init__(self, eng):
-        self.prefill, self.decode = [], []
+        self.prefill, self.decode, self.live_pages = [], [], []
         pf, df = eng._paged_prefill, eng._paged_decode
+        pg = eng.ecfg.page_size
 
         def prefill(params, kp, vp, grid, bt):
             g = np.asarray(grid)
@@ -81,7 +85,10 @@ class CallLog:
             return pf(params, kp, vp, grid, bt)
 
         def decode(params, kp, vp, toks, ctx, bt, active):
-            self.decode.append(int(np.asarray(active).sum()))
+            a = np.asarray(active)
+            self.decode.append(int(a.sum()))
+            self.live_pages.append(int((-(-(np.asarray(ctx)[a] + 1)
+                                          // pg)).sum()))
             return df(params, kp, vp, toks, ctx, bt, active)
 
         eng._paged_prefill, eng._paged_decode = prefill, decode
@@ -97,6 +104,10 @@ def check_counts(eng, res, calls=None):
     # every decode row emits one token, and only committed batches emit
     assert ps[SK.DECODE_ROWS] == decode_tokens > 0
     assert ps[SK.DECODE_CALLS] == sum(1 for b in batches if b.num_decode)
+    # a decode row attends over at least one page, at most a full table
+    max_pages = -(-eng.ecfg.cache_len // eng.ecfg.page_size)
+    assert (ps[SK.DECODE_ROWS] <= ps[SK.DECODE_LIVE_PAGES]
+            <= ps[SK.DECODE_ROWS] * max_pages)
     # the prompt tokens computed: those granted, less the prefix-shared
     # ones that a page mapping satisfied
     assert ps[SK.PREFILL_REAL_TOKENS] == prefill_tokens - shared
@@ -108,6 +119,7 @@ def check_counts(eng, res, calls=None):
         assert ps[SK.PREFILL_REAL_TOKENS] == sum(n for _, n in calls.prefill)
         assert ps[SK.DECODE_CALLS] == len(calls.decode)
         assert ps[SK.DECODE_ROWS] == sum(calls.decode)
+        assert ps[SK.DECODE_LIVE_PAGES] == sum(calls.live_pages)
 
 
 def check_stamps(reqs):
@@ -126,6 +138,25 @@ def test_counters_match_the_programs_inputs_and_the_batch_logs(model):
     check_stamps(reqs)
     # a result carries a copy, not the live dict
     assert res.phase_stats is not eng.phase_stats
+
+
+def test_decode_live_pages_sum_the_pages_each_decode_row_reads(model):
+    """A decode at context m reads m + 1 positions, ceil((m + 1) / page)
+    pages; a request of I prompt tokens and O outputs decodes at m = I
+    to I + O - 2 (its prefill emits the first output)."""
+    eng = build(model)
+    calls = CallLog(eng)
+    pg = eng.ecfg.page_size
+    shapes = ((5, 6), (7, 4), (14, 12))     # (I, O): crossing pages of 8
+    reqs = [Request(rid=i, input_len=n, output_len=o, arrival=0.0,
+                    prompt=[(31 * i + t) % 97 + 1 for t in range(n)])
+            for i, (n, o) in enumerate(shapes)]
+    eng.run(reqs)
+    ps = eng.phase_stats
+    assert ps[SK.DECODE_ROWS] == sum(o - 1 for _, o in shapes)
+    assert ps[SK.DECODE_LIVE_PAGES] == sum(
+        -(-(m + 1) // pg) for n, o in shapes for m in range(n, n + o - 1))
+    assert ps[SK.DECODE_LIVE_PAGES] == sum(calls.live_pages)
 
 
 def test_counters_on_the_slot_plane(model):

@@ -12,6 +12,7 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_reference
 from repro.kernels.paged_attention.ops import (decode_attention_dense,
                                                paged_decode_attention)
+from repro.kernels.paged_attention.paged_attention import pages_per_block
 from repro.kernels.paged_attention.ref import paged_decode_reference
 from repro.kernels.rwkv_scan.ops import wkv6
 from repro.kernels.rwkv_scan.ref import wkv6_reference
@@ -87,10 +88,22 @@ def test_flash_attention_block_shapes(bq, bk):
 # paged decode attention
 # --------------------------------------------------------------------- #
 
+def edge_contexts(page, npages):
+    """Contexts at each edge of a page and of a grid block, from 0 to
+    the full table."""
+    block = pages_per_block(page, npages) * page
+    full = page * npages
+    return sorted({c for c in (0, 1, page - 1, page, block, block + 1, full)
+                   if c <= full})
+
+
 @pytest.mark.parametrize("B,H,Hkv,D,page,npages", [
     (2, 8, 2, 64, 16, 4),
     (4, 4, 4, 64, 32, 2),
     (1, 8, 1, 128, 64, 3),
+    (2, 32, 8, 128, 16, 20),     # Qwen3-4B widths; 2 blocks, the last short
+    (2, 15, 5, 64, 16, 20),      # SmolLM-360M widths
+    (2, 8, 2, 64, 64, 6),        # page 64: 4 pages a block, 2 blocks
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_sweep(B, H, Hkv, D, page, npages, dtype):
@@ -108,9 +121,35 @@ def test_paged_decode_sweep(B, H, Hkv, D, page, npages, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))
 
+    # one row at each edge context; every table entry past a row's
+    # context points at another row's live page or at a page of NaNs,
+    # so an entry the kernel read would show
+    ctx = jnp.asarray(edge_contexts(page, npages), jnp.int32)
+    R = ctx.shape[0]
+    P = R * npages + 1
+    q = jax.random.normal(K(5), (R, H, D), dtype)
+    kp = jax.random.normal(K(6), (P, Hkv, page, D), dtype).at[P - 1].set(
+        jnp.nan)
+    vp = jax.random.normal(K(7), (P, Hkv, page, D), dtype).at[P - 1].set(
+        jnp.nan)
+    own = jax.random.permutation(K(8), P - 1).reshape(R, npages)
+    own = own.astype(jnp.int32)
+    entry = jnp.arange(npages)[None, :]
+    past = entry * page >= ctx[:, None]
+    tail = jnp.where(entry % 2 == 0, jnp.roll(own, 1, axis=0), P - 1)
+    out = paged_decode_attention(q, kp, vp, jnp.where(past, tail, own), ctx)
+    ref = paged_decode_reference(q.astype(jnp.float32),
+                                 kp.astype(jnp.float32),
+                                 vp.astype(jnp.float32), own, ctx)
+    out = np.asarray(out, np.float32)
+    live = np.asarray(ctx) > 0
+    np.testing.assert_array_equal(out[~live], 0.0)    # context 0: zeros
+    np.testing.assert_allclose(out[live], np.asarray(ref, np.float32)[live],
+                               **tol(dtype))
+
 
 def test_paged_decode_short_context_skips_pages():
-    """ctx=1: only the first page contributes (pl.when skip path)."""
+    """ctx=1: only the first page contributes (no later page is read)."""
     B, H, Hkv, D, page, npages = 1, 4, 2, 64, 16, 4
     P = B * npages
     q = jax.random.normal(K(0), (B, H, D))

@@ -5,7 +5,8 @@ compiled ahead of time for a chip that is described and not attached.
 That catches what interpret mode cannot: block shapes the Mosaic tiling
 refuses and kernels that overflow VMEM.  Shapes are those the engine's
 paged plane serves with ``chip_smoke.py``: page 16, prefill chunk 256,
-8 rows over a 2048-token cache, bfloat16 pools.
+8 rows over a 2048-token cache, bfloat16 pools; the decode kernel also
+at the shape of the ``qwen3-4b.analytics-prefix`` benchmark cell.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -19,13 +20,16 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.paged_attention.ops import paged_decode_attention
 from repro.kernels.paged_attention.paged_attention import (paged_decode_bhd,
                                                            paged_prefill_bhd)
 
 PAGE, CHUNK, ROWS, CACHE_LEN, POOL_PAGES = 16, 256, 8, 2048, 512
+CELL_ROWS, CELL_POOL_PAGES = 16, 1100     # 128 pages of 16 a row
 WIDTHS = {                       # name: (Hkv, G, D)
     "qwen3-4b": (8, 4, 128),
     "tinyllama-1.1b": (4, 8, 64),
+    "smollm-360m": (5, 3, 64),
 }
 
 
@@ -78,6 +82,24 @@ def test_paged_decode_compiles_for_v5e(spec, no_compile_cache, width):
     compiled = fn.lower(spec((ROWS, Hkv, G, D)), pool, pool, tables,
                         spec((ROWS,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_paged_decode_compiles_at_the_cell_shape(spec, no_compile_cache,
+                                                 width):
+    """The jitted entry at the cell's shape; its custom call keeps the
+    name the trace reader of ``paged_decode_roofline`` matches."""
+    Hkv, G, D = WIDTHS[width]
+    pool = spec((CELL_POOL_PAGES, Hkv, PAGE, D))
+    fn = jax.jit(functools.partial(paged_decode_attention, interpret=False))
+    text = fn.lower(spec((CELL_ROWS, Hkv * G, D)), pool, pool,
+                    spec((CELL_ROWS, CACHE_LEN // PAGE), jnp.int32),
+                    spec((CELL_ROWS,), jnp.int32)).compile().as_text()
+    calls = [line.split("=")[0].strip().lstrip("%")
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    assert all(c.startswith("paged_decode_attention") for c in calls), calls
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
