@@ -42,9 +42,9 @@ from repro.core import (Request, TheoreticalCostModel,  # noqa: E402
                         hardware_for_device, make_scheduler)
 from repro.core import stat_keys as SK  # noqa: E402
 from repro.kernels.paged_attention.ops import (  # noqa: E402
-    paged_decode_attention, paged_prefill_attention)
+    paged_decode, paged_prefill_attention)
 from repro.kernels.paged_attention.ref import (  # noqa: E402
-    paged_decode_reference, paged_prefill_reference)
+    paged_decode_reference, paged_prefill_reference, scatter_rows)
 from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
 from repro.models import model as M  # noqa: E402
 from repro.serving import Engine, EngineConfig, generate_reference  # noqa: E402
@@ -123,23 +123,42 @@ def phase_kernels(cfg, seed: int) -> None:
     H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     B, maxp = NSLOTS, CACHE_LEN // PAGE
     P = B * maxp + 64                          # 64 pages no table maps
-    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 10)
     bf = jnp.bfloat16
     kp = jax.random.normal(ks[0], (P, Hkv, PAGE, D), bf)
     vp = jax.random.normal(ks[1], (P, Hkv, PAGE, D), bf)
+    # the kernels index layer 1 of a stack of two; layer 0 holds NaNs,
+    # which a read of the wrong layer would carry into the outputs
+    layer = 1
+    k_stack = jnp.stack([jnp.full_like(kp, jnp.nan), kp])
+    v_stack = jnp.stack([jnp.full_like(vp, jnp.nan), vp])
     bt = jax.random.permutation(ks[2], P)[:B * maxp] \
         .reshape(B, maxp).astype(jnp.int32)
     log("kernels", f"Qwen3-4B widths H={H} Hkv={Hkv} D={D}, page {PAGE}, "
-                   f"chunk {CHUNK}, {B} rows x {maxp} pages, pool {P} pages")
+                   f"chunk {CHUNK}, {B} rows x {maxp} pages, pool {P} pages, "
+                   f"layer {layer} of 2")
 
-    # decode: one query token per row over up to CACHE_LEN positions
+    # decode: one query token per row over up to CACHE_LEN positions,
+    # its new K/V row written at position ctx - 1 first
     q = jax.random.normal(ks[3], (B, H, D), bf)
+    kn = jax.random.normal(ks[7], (B, Hkv, D), bf)
+    vn = jax.random.normal(ks[8], (B, Hkv, D), bf)
     ctx = jnp.asarray([1, 15, 16, 17, 700, 1024, 2047, 2048], jnp.int32)
-    got = paged_decode_attention(q, kp, vp, bt, ctx)
+    got, nk, nv = jax.jit(paged_decode)(q, kn, vn, k_stack, v_stack, bt, ctx,
+                                        layer)
+    pos = ctx - 1
+    dest = jnp.take_along_axis(bt, (pos // PAGE)[:, None], 1)[:, 0] * PAGE \
+        + pos % PAGE
+    rk, rv = scatter_rows(kp, dest, kn), scatter_rows(vp, dest, vn)
     want = paged_decode_reference(q.astype(jnp.float32),
-                                  kp.astype(jnp.float32),
-                                  vp.astype(jnp.float32), bt, ctx)
+                                  rk.astype(jnp.float32),
+                                  rv.astype(jnp.float32), bt, ctx)
     _check_close("kernels", "paged decode", got, want)
+    for name, new, ref in (("K", nk[layer], rk), ("V", nv[layer], rv)):
+        if not np.array_equal(np.asarray(new, np.float32),
+                              np.asarray(ref, np.float32)):
+            raise AssertionError(f"decode wrote its {name} rows wrong")
+    _check_other_layer("kernels", "paged decode", nk, nv, layer)
 
     # prefill: full, partial, mid-page and inert rows
     qc = jax.random.normal(ks[4], (B, CHUNK, H, D), bf)
@@ -148,23 +167,33 @@ def phase_kernels(cfg, seed: int) -> None:
     starts = jnp.asarray([0, 0, 16, 5, 512, 1000, 1792, 0], jnp.int32)
     lengths = jnp.asarray([CHUNK, 1, CHUNK, 100, 37, CHUNK, CHUNK, 0],
                           jnp.int32)
-    out, nk, nv = paged_prefill_attention(qc, kc, vc, kp, vp, bt, starts,
-                                          lengths)
+    out, nk, nv = paged_prefill_attention(qc, kc, vc, k_stack, v_stack, bt,
+                                          starts, lengths, layer)
     rout, rk, rv = paged_prefill_reference(qc, kc, vc, kp, vp, bt, starts,
                                            lengths)
     valid = np.arange(CHUNK)[None, :] < np.asarray(lengths)[:, None]
     _check_close("kernels", "paged prefill output (real rows)",
                  np.asarray(out, np.float32)[valid],
                  np.asarray(rout, np.float32)[valid])
-    _check_close("kernels", "paged prefill K pool", nk, rk)
-    _check_close("kernels", "paged prefill V pool", nv, rv)
+    _check_close("kernels", "paged prefill K pool", nk[layer], rk)
+    _check_close("kernels", "paged prefill V pool", nv[layer], rv)
     unowned = np.setdiff1d(np.arange(P), np.asarray(bt).ravel())
-    for name, new, old in (("K", nk, kp), ("V", nv, vp)):
+    for name, new, old in (("K", nk[layer], kp), ("V", nv[layer], vp)):
         if not np.array_equal(np.asarray(new)[unowned],
                               np.asarray(old)[unowned]):
             raise AssertionError(f"prefill changed unowned {name} pages")
+    _check_other_layer("kernels", "paged prefill", nk, nv, layer)
     log("kernels", f"{len(unowned)} unowned pages of each pool are "
                    "bit-identical after prefill")
+
+
+def _check_other_layer(phase: str, name: str, nk, nv, layer: int) -> None:
+    """Every layer but ``layer`` of both stacks still holds only NaNs."""
+    for new in (nk, nv):
+        other = np.delete(np.asarray(new, np.float32), layer, axis=0)
+        if not np.isnan(other).all():
+            raise AssertionError(f"{name} wrote another layer")
+    log(phase, f"{name}: the other layer of each stack is untouched")
 
 
 def assert_kernels_compiled(eng: Engine) -> None:

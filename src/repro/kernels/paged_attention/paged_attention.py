@@ -1,9 +1,14 @@
 """Paged attention kernels (Pallas, TPU target) — flash reduction over pages.
 
-Two ops share the head-major pooled ``(num_pages, Hkv, page_size, D)``
-layout and the scalar-prefetched block-table addressing:
+Two ops share the head-major pools, stacked over layers
+``(L, num_pages, Hkv, page_size, D)``, and the scalar-prefetched
+addressing: the layer index and the block table.  A kernel reads and
+writes pages of its layer of the whole stack in place, so a layer loop
+carries the stacks and never slices one layer's pool out.
 
-* ``paged_decode_bhd`` — flash-decoding, one token per row.
+* ``paged_decode_bhd`` — flash-decoding, one token per row, over pages
+  that already hold the row's new token (``write_rows`` puts it there
+  first, in place: write-then-attend).
 * ``paged_prefill_bhd`` — the FUSED chunked-prefill step: per grid
   instance it (a) overlays the chunk's K/V rows onto the owned page it
   is visiting (one-hot MXU matmul, written back through
@@ -18,8 +23,9 @@ layout and the scalar-prefetched block-table addressing:
 Both find pages through the *scalar-prefetched* block table
 (``PrefetchScalarGridSpec``): a BlockSpec index_map, or the kernel's
 own copy, dereferences ``block_tables[b, ...]``, so the TPU DMA engine
-streams the pages a row owns, never the whole pool.  The fp32 (acc, m, l) scratch persists
-across the innermost grid axis, which runs in order on the chip.
+streams the pages a row owns, never the whole pool.  The fp32 (acc, m,
+l) scratch persists across the innermost grid axis, which runs in order
+on the chip.
 
 Decode runs one grid instance per (row, block of ``ppb`` table
 entries), for all kv heads at once: ``ppb`` comes from the page size
@@ -62,7 +68,7 @@ def pages_per_block(page_size: int, npages: int) -> int:
     return max(1, min(npages, MAX_BLOCK_PAGES, BLOCK_TOKENS // page_size))
 
 
-def _pd_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
+def _pd_kernel(block_tables_ref, context_lens_ref, layer_ref,  # prefetch
                q_ref, *refs, page_size: int, ppb: int, npages: int,
                scale: float, dma: bool):
     if dma:
@@ -74,6 +80,7 @@ def _pd_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
     j = pl.program_id(1)
     nblk = pl.num_programs(1)
     block = ppb * page_size
+    layer = layer_ref[0]
     ctx = jnp.minimum(context_lens_ref[b], npages * page_size)
     live_pages = (ctx + page_size - 1) // page_size
 
@@ -87,9 +94,11 @@ def _pd_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
         page = block_tables_ref[b, blk * ppb + i]
         slot = blk % 2
         rows = pl.ds(i * page_size, page_size)
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, :, rows],
+        return (pltpu.make_async_copy(k_hbm.at[layer, page],
+                                      k_buf.at[slot, :, rows],
                                       sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, :, rows],
+                pltpu.make_async_copy(v_hbm.at[layer, page],
+                                      v_buf.at[slot, :, rows],
                                       sems.at[1, slot]))
 
     def fetch(blk):
@@ -160,7 +169,7 @@ def _pd_kernel(block_tables_ref, context_lens_ref,  # scalar prefetch
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _pp_kernel(block_tables_ref, starts_ref, lengths_ref,  # scalar prefetch
+def _pp_kernel(block_tables_ref, starts_ref, lengths_ref, layer_ref,  # prefetch
                q_ref, kc_ref, vc_ref, kp_ref, vp_ref,
                o_ref, nkp_ref, nvp_ref,
                acc_ref, m_ref, l_ref, *,
@@ -241,47 +250,49 @@ def _pp_kernel(block_tables_ref, starts_ref, lengths_ref,  # scalar prefetch
 
 
 def paged_prefill_bhd(q: jnp.ndarray, kc: jnp.ndarray, vc: jnp.ndarray,
-                      k_pool: jnp.ndarray, v_pool: jnp.ndarray,
+                      k_pools: jnp.ndarray, v_pools: jnp.ndarray,
                       block_tables: jnp.ndarray, starts: jnp.ndarray,
-                      lengths: jnp.ndarray, *,
+                      lengths: jnp.ndarray, layer: jnp.ndarray, *,
                       interpret: bool = False
                       ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused paged-prefill: gather-write-attend in ONE pass.
 
     q (B, Hkv, c*G, D) — the chunk's queries, head-grouped; kc/vc
-    (B, Hkv, c, D) — the chunk's new K/V rows; pools (P, Hkv, page, D).
-    Returns (out (B, Hkv, c*G, D), new_k_pool, new_v_pool); the pools
-    are updated IN PLACE (``input_output_aliases``) — only pages the
-    block tables own are touched, every other page keeps its bytes.
+    (B, Hkv, c, D) — the chunk's new K/V rows; pools stacked over layers
+    (L, P, Hkv, page, D), of which layer ``layer`` ((1,) int32) is read
+    and written.  Returns (out (B, Hkv, c*G, D), new_k_pools,
+    new_v_pools); the pools are updated IN PLACE
+    (``input_output_aliases``) — only that layer's pages the block tables
+    own are touched, every other byte keeps its value.
     """
     B, Hkv, cG, D = q.shape
     c = kc.shape[2]
     G = cG // c
-    P, _, page, _ = k_pool.shape
+    page = k_pools.shape[3]
     maxp = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
 
-    def pool_idx(b, h, ip, bt, st, ln):
+    def pool_idx(b, h, ip, bt, st, ln, ly):
         np_owned = jnp.maximum((st[b] + ln[b] + page - 1) // page, 1)
-        return (bt[b, jnp.minimum(ip, np_owned - 1)], h, 0, 0)
+        return (ly[0], bt[b, jnp.minimum(ip, np_owned - 1)], h, 0, 0)
 
+    def row_idx(b, h, ip, bt, st, ln, ly):
+        return (b, h, 0, 0)
+
+    pool = pl.BlockSpec((None, 1, 1, page, D), pool_idx)
     kernel = functools.partial(_pp_kernel, page_size=page, chunk=c,
                                groups=G, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, Hkv, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, cG, D), lambda b, h, ip, bt, st, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, c, D), lambda b, h, ip, bt, st, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, c, D), lambda b, h, ip, bt, st, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, page, D), pool_idx),
-            pl.BlockSpec((1, 1, page, D), pool_idx),
+            pl.BlockSpec((1, 1, cG, D), row_idx),
+            pl.BlockSpec((1, 1, c, D), row_idx),
+            pl.BlockSpec((1, 1, c, D), row_idx),
+            pool,
+            pool,
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, cG, D), lambda b, h, ip, bt, st, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, page, D), pool_idx),
-            pl.BlockSpec((1, 1, page, D), pool_idx),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, cG, D), row_idx), pool, pool],
         scratch_shapes=[
             pltpu.VMEM((cG, D), jnp.float32),
             pltpu.VMEM((cG, 1), jnp.float32),
@@ -293,23 +304,25 @@ def paged_prefill_bhd(q: jnp.ndarray, kc: jnp.ndarray, vc: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((B, Hkv, cG, D), q.dtype),
-            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
+            jax.ShapeDtypeStruct(k_pools.shape, k_pools.dtype),
+            jax.ShapeDtypeStruct(v_pools.shape, v_pools.dtype),
         ),
-        # flattened operand order: bt(0) st(1) ln(2) q(3) kc(4) vc(5)
-        # k_pool(6) v_pool(7); pools alias outputs 1/2 (in-place update)
-        input_output_aliases={6: 1, 7: 2},
+        # flattened operand order: bt(0) st(1) ln(2) layer(3) q(4) kc(5)
+        # vc(6) k_pools(7) v_pools(8); pools alias outputs 1/2 (in place)
+        input_output_aliases={7: 1, 8: 2},
         interpret=interpret,
-    )(block_tables, starts, lengths, q, kc, vc, k_pool, v_pool)
+    )(block_tables, starts, lengths, layer, q, kc, vc, k_pools, v_pools)
 
 
-def paged_decode_bhd(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
-                     block_tables: jnp.ndarray, context_lens: jnp.ndarray, *,
+def paged_decode_bhd(q: jnp.ndarray, k_pools: jnp.ndarray,
+                     v_pools: jnp.ndarray, block_tables: jnp.ndarray,
+                     context_lens: jnp.ndarray, layer: jnp.ndarray, *,
                      interpret: bool = False) -> jnp.ndarray:
-    """q (B, Hkv, G, D); pools (P, Hkv, page, D) -> out (B, Hkv, G, D).
+    """q (B, Hkv, G, D); pools stacked over layers (L, P, Hkv, page, D),
+    of which layer ``layer`` ((1,) int32) is read -> out (B, Hkv, G, D).
     A row of context 0 gets zeros."""
     B, Hkv, G, D = q.shape
-    P, _, page, _ = k_pool.shape
+    page = k_pools.shape[3]
     npages = block_tables.shape[1]
     ppb = pages_per_block(page, npages)
     scale = 1.0 / math.sqrt(D)
@@ -323,31 +336,32 @@ def paged_decode_bhd(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
         the context re-aims at the row's last live page of the same
         operand, so the pipeline, seeing its block index unchanged,
         copies nothing; a row of context 0 stays on its first entry."""
-        def index(b, j, bt, cl):
+        def index(b, j, bt, cl, ly):
             live = jnp.maximum(
                 (jnp.minimum(cl[b], npages * page) + page - 1) // page, 1)
             e = jnp.minimum(jnp.minimum(j, (live - 1) // ppb) * ppb + i,
                             live - 1)
-            return (bt[b, e], 0, 0, 0)
-        return pl.BlockSpec((1, Hkv, page, D), index)
+            return (ly[0], bt[b, e], 0, 0, 0)
+        return pl.BlockSpec((None, 1, Hkv, page, D), index)
 
     if dma:
         hbm = pl.BlockSpec(memory_space=pl.ANY)
-        pool_specs, pools = [hbm, hbm], (k_pool, v_pool)
-        buf = pltpu.VMEM((2, Hkv, ppb * page, D), k_pool.dtype)
+        pool_specs, pools = [hbm, hbm], (k_pools, v_pools)
+        buf = pltpu.VMEM((2, Hkv, ppb * page, D), k_pools.dtype)
         copy_scratch = [buf, buf, pltpu.SemaphoreType.DMA((2, 2))]
     else:
         # each pool goes in once per page of a block, one pipelined
         # operand each; a custom call's operands are references, so no
         # pool is copied
         pages = [page_spec(i) for i in range(ppb)]
-        pool_specs, pools = pages + pages, (k_pool,) * ppb + (v_pool,) * ppb
+        pool_specs = pages + pages
+        pools = (k_pools,) * ppb + (v_pools,) * ppb
         copy_scratch = []
-    row = pl.BlockSpec((1, Hkv, G, D), lambda b, j, bt, cl: (b, 0, 0, 0))
+    row = pl.BlockSpec((1, Hkv, G, D), lambda b, j, bt, cl, ly: (b, 0, 0, 0))
     kernel = functools.partial(_pd_kernel, page_size=page, ppb=ppb,
                                npages=npages, scale=scale, dma=dma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, pl.cdiv(npages, ppb)),
         in_specs=[row, *pool_specs],
         out_specs=row,
@@ -365,4 +379,4 @@ def paged_decode_bhd(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, context_lens, q, *pools)
+    )(block_tables, context_lens, layer, q, *pools)

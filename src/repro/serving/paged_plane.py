@@ -50,7 +50,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels.paged_attention import ops as pa_ops
-from repro.kernels.paged_attention.ref import scatter_rows as _scatter_rows
 from repro.models import attention as attn
 from repro.models import model as M
 from repro.models.common import rms_norm
@@ -64,45 +63,40 @@ def paged_supported(cfg: ModelConfig) -> bool:
 
 
 def _attn_paged_chunk(lp: Any, cfg: ModelConfig, h: jnp.ndarray,
-                      k_pool: jnp.ndarray, v_pool: jnp.ndarray,
-                      starts: jnp.ndarray, lengths: jnp.ndarray,
-                      block_tables: jnp.ndarray
+                      k_pools: jnp.ndarray, v_pools: jnp.ndarray,
+                      layer: jnp.ndarray, starts: jnp.ndarray,
+                      lengths: jnp.ndarray, block_tables: jnp.ndarray
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Chunked prefill attention against pooled KV.  h (B, c, d); pools
-    (P, Hkv, page, D); starts/lengths (B,); block_tables (B, maxp).
-    Returns (attn out (B, c, q_dim-projected), new pools)."""
+    stacked over layers (L, P, Hkv, page, D), of which ``layer`` is this
+    one; starts/lengths (B,); block_tables (B, maxp).  Returns (attn out
+    (B, c, q_dim-projected), new pools)."""
     B, c, _ = h.shape
     positions = starts[:, None] + jnp.arange(c)[None, :]        # (B, c)
     q, k, v = attn._project_qkv(lp, cfg, h, positions)
-    out, new_k, new_v = pa_ops.paged_prefill(
-        q, k, v, k_pool, v_pool, block_tables, starts, lengths)
+    out, k_pools, v_pools = pa_ops.paged_prefill(
+        q, k, v, k_pools, v_pools, block_tables, starts, lengths, layer)
     out = out.reshape(B, c, cfg.q_dim) @ lp["wo"]
-    return out, new_k, new_v
+    return out, k_pools, v_pools
 
 
 def _attn_paged_decode(lp: Any, cfg: ModelConfig, h: jnp.ndarray,
-                       k_pool: jnp.ndarray, v_pool: jnp.ndarray,
-                       ctx: jnp.ndarray, block_tables: jnp.ndarray,
-                       active: jnp.ndarray
+                       k_pools: jnp.ndarray, v_pools: jnp.ndarray,
+                       layer: jnp.ndarray, ctx: jnp.ndarray,
+                       block_tables: jnp.ndarray, active: jnp.ndarray
                        ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One-token decode against pooled KV.  h (B, 1, d); ctx (B,) valid
     context (also the new token's position); active (B,) row mask."""
     B = h.shape[0]
-    P, pg = k_pool.shape[0], k_pool.shape[2]
-    maxp = block_tables.shape[1]
-    positions = ctx[:, None]
-    q, k, v = attn._project_qkv(lp, cfg, h, positions)
-    page_idx = jnp.take_along_axis(
-        block_tables, jnp.clip(positions // pg, 0, maxp - 1), axis=1)[:, 0]
-    dest = jnp.where(active, page_idx * pg + ctx % pg, P * pg)
-    new_k = _scatter_rows(k_pool, dest, k[:, 0])
-    new_v = _scatter_rows(v_pool, dest, v[:, 0])
-    # write-then-attend: context_lens = ctx + 1 includes the new token;
-    # an idle slot attends over nothing (its output is never read)
-    out = pa_ops.paged_decode(q[:, 0], new_k, new_v, block_tables,
-                              jnp.where(active, ctx + 1, 0))
+    q, k, v = attn._project_qkv(lp, cfg, h, ctx[:, None])
+    # write-then-attend: the new row lands at position ctx and
+    # context_lens = ctx + 1 includes it; an idle row writes and attends
+    # over nothing (its output is never read)
+    out, k_pools, v_pools = pa_ops.paged_decode(
+        q[:, 0], k[:, 0], v[:, 0], k_pools, v_pools, block_tables,
+        jnp.where(active, ctx + 1, 0), layer)
     out = out.reshape(B, cfg.q_dim) @ lp["wo"]
-    return out[:, None, :], new_k, new_v
+    return out[:, None, :], k_pools, v_pools
 
 
 def build_paged_fns(cfg: ModelConfig, *, moe_impl: str = "dense"
@@ -117,62 +111,62 @@ def build_paged_fns(cfg: ModelConfig, *, moe_impl: str = "dense"
                       block_tables (B, maxp), active (B,))
         -> (greedy ids (B,), new_k_pools, new_v_pools)
 
-    Pools are stacked over layers: (L, P, Hkv, page, D).  Sampling is
-    fused (argmax over the real vocabulary on device).  The attention
-    backend of both steps is picked by the JAX backend alone
-    (``ops.paged_prefill`` / ``ops.paged_decode``): the Pallas kernels
-    on an accelerator, the jnp reference on the CPU.
+    Pools are stacked over layers: (L, P, Hkv, page, D).  The layer loop
+    carries the whole stack and hands it to the attention ops with the
+    layer's index, so no step slices one layer's pool out or stacks it
+    back.  Sampling is fused (argmax over the real vocabulary on device).
+    The attention backend of both steps is picked by the JAX backend
+    alone (``ops.paged_prefill`` / ``ops.paged_decode``): the Pallas
+    kernels on an accelerator, the jnp reference on the CPU.
     """
     if not paged_supported(cfg):
         raise ValueError("paged pools need unbounded dense attention, "
                          f"got {cfg.family!r}")
     vocab = cfg.vocab_size
 
-    def _block(lp, x, attn_out):
-        x = x + attn_out
-        h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        return x + M._mlp_or_moe(cfg, lp, h2, moe_impl)
+    def _layers(params, x, k_pools, v_pools, attend):
+        """x through every layer; ``attend(lp, h, k_pools, v_pools,
+        layer)`` -> (attn out, new pools)."""
+        def body(carry, per_layer):
+            xc, kp, vp = carry
+            lp, layer = per_layer
+            h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
+            a, kp, vp = attend(lp["attn"], h, kp, vp, layer)
+            xc = xc + a
+            h2 = rms_norm(xc, lp["ln2"], cfg.norm_eps)
+            return (xc + M._mlp_or_moe(cfg, lp, h2, moe_impl), kp, vp), None
+
+        layer_ids = jnp.arange(k_pools.shape[0], dtype=jnp.int32)
+        (x, k_pools, v_pools), _ = jax.lax.scan(
+            body, (x, k_pools, v_pools), (params["layers"], layer_ids))
+        return rms_norm(x, params["ln_f"], cfg.norm_eps), k_pools, v_pools
 
     def prefill_fn(params, k_pools, v_pools, tokens, starts, lengths,
                    block_tables):
         B, c = tokens.shape
         positions = starts[:, None] + jnp.arange(c)[None, :]
         x, _ = M._embed(cfg, params, tokens, positions, None)
-
-        def body(xc, per_layer):
-            lp, (kp, vp) = per_layer
-            h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
-            a, kp, vp = _attn_paged_chunk(lp["attn"], cfg, h, kp, vp,
-                                          starts, lengths, block_tables)
-            return _block(lp, xc, a), (kp, vp)
-
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], (k_pools, v_pools)))
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        x, k_pools, v_pools = _layers(
+            params, x, k_pools, v_pools,
+            lambda lp, h, kp, vp, layer: _attn_paged_chunk(
+                lp, cfg, h, kp, vp, layer, starts, lengths, block_tables))
         last = jnp.maximum(lengths - 1, 0)
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
         logits = M._logits(cfg, params, x_last)
         toks = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
-        return toks, new_k, new_v
+        return toks, k_pools, v_pools
 
     def paged_decode_step(params, k_pools, v_pools, tokens, ctx,
                           block_tables, active):
         if tokens.ndim == 1:
             tokens = tokens[:, None]
         x, _ = M._embed(cfg, params, tokens, ctx[:, None], None)
-
-        def body(xc, per_layer):
-            lp, (kp, vp) = per_layer
-            h = rms_norm(xc, lp["ln1"], cfg.norm_eps)
-            a, kp, vp = _attn_paged_decode(lp["attn"], cfg, h, kp, vp,
-                                           ctx, block_tables, active)
-            return _block(lp, xc, a), (kp, vp)
-
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], (k_pools, v_pools)))
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        x, k_pools, v_pools = _layers(
+            params, x, k_pools, v_pools,
+            lambda lp, h, kp, vp, layer: _attn_paged_decode(
+                lp, cfg, h, kp, vp, layer, ctx, block_tables, active))
         logits = M._logits(cfg, params, x[:, 0])
         toks = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
-        return toks, new_k, new_v
+        return toks, k_pools, v_pools
 
     return prefill_fn, paged_decode_step

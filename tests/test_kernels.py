@@ -11,7 +11,8 @@ import pytest
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_reference
 from repro.kernels.paged_attention.ops import (decode_attention_dense,
-                                               paged_decode_attention)
+                                               paged_decode_attention,
+                                               write_rows)
 from repro.kernels.paged_attention.paged_attention import pages_per_block
 from repro.kernels.paged_attention.ref import paged_decode_reference
 from repro.kernels.rwkv_scan.ops import wkv6
@@ -114,7 +115,7 @@ def test_paged_decode_sweep(B, H, Hkv, D, page, npages, dtype):
     bt = jax.random.permutation(K(3), P)[:B * npages].reshape(B, npages)
     bt = bt.astype(jnp.int32)
     ctx = jax.random.randint(K(4), (B,), 1, page * npages + 1)
-    out = paged_decode_attention(q, kp, vp, bt, ctx)
+    out = paged_decode_attention(q, kp[None], vp[None], bt, ctx)
     ref = paged_decode_reference(q.astype(jnp.float32),
                                  kp.astype(jnp.float32),
                                  vp.astype(jnp.float32), bt, ctx)
@@ -137,13 +138,60 @@ def test_paged_decode_sweep(B, H, Hkv, D, page, npages, dtype):
     entry = jnp.arange(npages)[None, :]
     past = entry * page >= ctx[:, None]
     tail = jnp.where(entry % 2 == 0, jnp.roll(own, 1, axis=0), P - 1)
-    out = paged_decode_attention(q, kp, vp, jnp.where(past, tail, own), ctx)
+    out = paged_decode_attention(q, kp[None], vp[None],
+                                 jnp.where(past, tail, own), ctx)
     ref = paged_decode_reference(q.astype(jnp.float32),
                                  kp.astype(jnp.float32),
                                  vp.astype(jnp.float32), own, ctx)
     out = np.asarray(out, np.float32)
     live = np.asarray(ctx) > 0
     np.testing.assert_array_equal(out[~live], 0.0)    # context 0: zeros
+    np.testing.assert_allclose(out[live], np.asarray(ref, np.float32)[live],
+                               **tol(dtype))
+
+
+@pytest.mark.parametrize("D", [128, 64])        # own copies; pipelined pages
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_writes_then_reads_one_layer_of_a_stack(D, dtype):
+    """Layer 1 of a 3-layer stack, as ``ops.paged_decode`` runs it: each
+    live row's new K/V row lands at (1, page, :, slot, :) in place, then
+    the row attends over it; an idle row writes nothing.  The other layers hold NaNs, so a read of them
+    would show, and they, like every page of layer 1 no row writes,
+    keep their bytes."""
+    B, H, Hkv, page, npages, L, layer = 5, 8, 2, 16, 20, 3, 1
+    P = B * npages + 3
+    kp = jnp.full((L, P, Hkv, page, D), jnp.nan, dtype)
+    vp = jnp.full((L, P, Hkv, page, D), jnp.nan, dtype)
+    kp = kp.at[layer].set(jax.random.normal(K(1), (P, Hkv, page, D), dtype))
+    vp = vp.at[layer].set(jax.random.normal(K(2), (P, Hkv, page, D), dtype))
+    q = jax.random.normal(K(0), (B, H, D), dtype)
+    k = jax.random.normal(K(3), (B, Hkv, D), dtype)
+    v = jax.random.normal(K(4), (B, Hkv, D), dtype)
+    bt = jax.random.permutation(K(5), P)[:B * npages].reshape(B, npages)
+    bt = bt.astype(jnp.int32)
+    # idle; the first position; a page's last; the next page's first;
+    # the table's last, past a block of 16 pages
+    ctx = jnp.asarray([0, 1, page, page + 1, npages * page], jnp.int32)
+
+    nk, nv = (write_rows(pools, new, bt, ctx, layer)
+              for pools, new in ((kp, k), (vp, v)))
+    out = paged_decode_attention(q, nk, nv, bt, ctx, layer)
+
+    pos = np.asarray(ctx) - 1
+    live = pos >= 0
+    # float32 holds every bfloat16 exactly, NaNs included
+    want_k, want_v = np.array(kp, np.float32), np.array(vp, np.float32)
+    for b in np.flatnonzero(live):
+        at = int(np.asarray(bt)[b, pos[b] // page]), pos[b] % page
+        want_k[layer, at[0], :, at[1]] = np.asarray(k, np.float32)[b]
+        want_v[layer, at[0], :, at[1]] = np.asarray(v, np.float32)[b]
+    np.testing.assert_array_equal(np.asarray(nk, np.float32), want_k)
+    np.testing.assert_array_equal(np.asarray(nv, np.float32), want_v)
+    ref = paged_decode_reference(q.astype(jnp.float32),
+                                 jnp.asarray(want_k[layer]),
+                                 jnp.asarray(want_v[layer]), bt, ctx)
+    out = np.asarray(out, np.float32)
+    np.testing.assert_array_equal(out[~live], 0.0)
     np.testing.assert_allclose(out[live], np.asarray(ref, np.float32)[live],
                                **tol(dtype))
 
@@ -157,7 +205,7 @@ def test_paged_decode_short_context_skips_pages():
     vp = jax.random.normal(K(2), (P, Hkv, page, D))
     bt = jnp.arange(P).reshape(B, npages).astype(jnp.int32)
     ctx = jnp.array([1], jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, ctx)
+    out = paged_decode_attention(q, kp[None], vp[None], bt, ctx)
     ref = paged_decode_reference(q, kp, vp, bt, ctx)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)

@@ -38,8 +38,9 @@ def _mk(B, c, H, Hkv, D, page, maxp, spare=3, seed=0):
 def _both(q, k, v, kp, vp, bt, starts, lengths):
     starts = jnp.asarray(starts, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
-    got = paged_prefill_attention(q, k, v, kp, vp, bt, starts, lengths,
-                                  interpret=True)
+    out, nk, nv = paged_prefill_attention(q, k, v, kp[None], vp[None], bt,
+                                          starts, lengths, interpret=True)
+    got = out, nk[0], nv[0]
     want = paged_prefill_reference(q, k, v, kp, vp, bt, starts, lengths)
     return got, want
 
@@ -141,6 +142,39 @@ def test_prefill_kernel_cow_boundary_page():
     p0 = int(np.asarray(bt)[0, 0])
     np.testing.assert_array_equal(np.asarray(got[1])[p0, :, :5],
                                   np.asarray(kp)[p0, :, :5])
+
+
+def test_prefill_kernel_reads_and_writes_one_layer_of_a_stack():
+    """Layer 1 of a 3-layer stack, with a full, a partial and an inert
+    row: the chunk's real rows land in layer 1's owned pages, and
+    nothing else moves.  The other layers hold NaNs, so a read of them
+    would show in the output."""
+    B, c, H, Hkv, D, page, maxp, L, layer = 3, 8, 4, 2, 64, 8, 3, 3, 1
+    q, k, v, kp, vp, bt = _mk(B, c, H, Hkv, D, page, maxp, seed=17)
+    starts = jnp.asarray([0, 4, 0], jnp.int32)
+    lengths = jnp.asarray([c, 3, 0], jnp.int32)
+    k_stack = jnp.full((L,) + kp.shape, jnp.nan).at[layer].set(kp)
+    v_stack = jnp.full((L,) + vp.shape, jnp.nan).at[layer].set(vp)
+    out, nk, nv = paged_prefill_attention(q, k, v, k_stack, v_stack, bt,
+                                          starts, lengths, layer,
+                                          interpret=True)
+    want = paged_prefill_reference(q, k, v, kp, vp, bt, starts, lengths)
+    _assert_triple((out, nk[layer], nv[layer]), want, lengths, c)
+    for new, old in ((nk, k_stack), (nv, v_stack)):
+        new, old = np.asarray(new), np.asarray(old)
+        others = np.arange(L) != layer
+        np.testing.assert_array_equal(new[others], old[others])
+        unowned = np.setdiff1d(np.arange(kp.shape[0]), np.asarray(bt[:2]))
+        np.testing.assert_array_equal(new[layer, unowned], old[layer, unowned])
+    # the rows written are exactly the real ones: row 0's c from 0, row
+    # 1's 3 from position 4; every other position of layer 1 keeps its
+    # bytes
+    written = np.zeros(np.asarray(kp).shape[:1] + (page,), bool)
+    for b, (st, ln) in enumerate(((0, c), (4, 3))):
+        for p in range(st, st + ln):
+            written[int(np.asarray(bt)[b, p // page]), p % page] = True
+    moved = np.any(np.asarray(nk[layer]) != np.asarray(kp), axis=(1, 3))
+    np.testing.assert_array_equal(moved, written)
 
 
 # --------------------------------------------------------------------- #
